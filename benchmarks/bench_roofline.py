@@ -52,8 +52,8 @@ def _shapes():
 
     def cd(B):
         R, K_, F = 6, 4, 16
-        shares = jnp.asarray(rng.standard_normal((B, R, F)), jnp.float32)
-        dec = jnp.asarray(rng.standard_normal((B, K_, R)), jnp.float32)
+        shares = jnp.asarray(rng.standard_normal((R, B, F)), jnp.float32)
+        dec = jnp.asarray(rng.standard_normal((K_, B, R)), jnp.float32)
         mask = jnp.ones((B, R), jnp.float32)
         flops = 2.0 * B * K_ * R * F
         nbytes = 4.0 * (B * R * F + B * K_ * R + B * R + B * K_ * F)

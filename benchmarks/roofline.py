@@ -1,62 +1,37 @@
 """Roofline report: the three roofline terms per (arch × shape × mesh) —
 the §Roofline table of EXPERIMENTS.md.
 
-Reads ``benchmarks/results/dryrun.json``; when the artifact is missing this
-module produces it itself by driving ``repro.launch.dryrun --tiny`` for a
-small default cell set (tiny configs on a few forced host devices) in a
-subprocess — the dry-run forces its host-device count via XLA_FLAGS at
-import, which cannot take effect in a process whose jax is already
-initialized. A prior full multi-pod sweep is therefore no longer a
-prerequisite; its artifact is simply used when present.
+Reads the dry-run artifact ``benchmarks/results/dryrun.json``. The dry-run
+forces its host-device count through ``XLA_FLAGS`` when it is imported, so
+it runs in a process of its own, before this report and not from it::
+
+  PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \\
+      --shape train_4k --tiny
+  PYTHONPATH=src python -m benchmarks.run --only benchmarks.roofline
+
+A missing artifact or a failed cell in it is an error, so
+``benchmarks/run.py`` exits non-zero instead of reporting the row.
 """
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 
 from benchmarks.common import emit
 
 RESULTS = pathlib.Path(__file__).resolve().parent / "results" / "dryrun.json"
 
-# the self-driven smoke cells: one dense train cell, one sub-quadratic
-# decode cell — enough to exercise every roofline term
-DEFAULT_CELLS = (
-    ("tinyllama-1.1b", "train_4k"),
-    ("mamba2-130m", "decode_32k"),
-)
-
-
-def _drive_tiny_dryrun(out: pathlib.Path) -> None:
-    """Compile the default smoke cells with ``repro.launch.dryrun --tiny``
-    (subprocess per cell so the forced host-device XLA flag applies)."""
-    env = dict(os.environ,
-               _DRYRUN_HOST_DEVICES="8",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
-    src = pathlib.Path(__file__).resolve().parents[1] / "src"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (str(src), env.get("PYTHONPATH")) if p)
-    for arch, shape in DEFAULT_CELLS:
-        subprocess.run(
-            [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
-             "--shape", shape, "--tiny", "--out", str(out)],
-            env=env, check=False, timeout=600,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-
 
 def main() -> None:
     if not RESULTS.exists():
-        _drive_tiny_dryrun(RESULTS)
-    if not RESULTS.exists():
-        emit("roofline/missing", 0.0, "tiny dry-run produced no artifact")
-        return
+        raise FileNotFoundError(
+            f"{RESULTS} is missing: run `python -m repro.launch.dryrun` "
+            f"(see this module's docstring) first")
     data = json.loads(RESULTS.read_text())
+    failed = sorted(key for key, rec in data.items() if not rec.get("ok"))
+    if failed:
+        raise RuntimeError(f"dry-run cells failed: {failed}")
     for key, rec in sorted(data.items()):
-        if not rec.get("ok"):
-            emit(f"roofline/{key}", 0.0, "FAILED")
-            continue
         r = rec["roofline"]
         bound = max(r["compute_s"], r["memory_s"], r["collective_s"])
         frac = r["compute_s"] / bound if bound > 0 else 0.0

@@ -15,7 +15,7 @@ import time
 import traceback
 
 MODULES = [
-    "benchmarks.roofline",             # drives a tiny dry-run if needed
+    "benchmarks.roofline",             # reads the dry-run artifact
     "benchmarks.bench_roofline",       # kernel efficiency vs measured roofline
     "benchmarks.sim_speed",            # Monte-Carlo engine: loop vs vectorized
     "benchmarks.plan_scale",           # PlanIR planner scale + controller

@@ -394,27 +394,33 @@ def failout_finetune(ens: Ensemble, teacher: TeacherBundle,
     return dataclasses.replace(ens, students=students, fc=fc)
 
 
+@functools.partial(jax.jit, static_argnames=("scfg", "sfwd", "tcfg", "dcfg"))
+def _distill_step(sparams, mom, tparams, part, x, y, *, scfg, sfwd, tcfg,
+                  dcfg):
+    # teacher weights and the partition are arguments, not closed-over
+    # constants: students of one arch and width share one compiled step
+    t_logits, t_feats, _ = cnn.wrn_forward(tparams, tcfg, x)
+    t_part = t_feats[:, part]
+
+    def loss_fn(p):
+        logits, feats, newp = sfwd(p, scfg, x, train=True)
+        return DS.distill_loss(logits, feats, t_logits, t_part, y, dcfg), newp
+
+    (loss, newp), grads = jax.value_and_grad(loss_fn, has_aux=True)(sparams)
+    sparams2, mom2 = sgd_update(sparams, grads, mom)
+    sparams2 = merge_bn_stats(sparams2, newp)   # BN running stats only
+    return sparams2, mom2, loss
+
+
 def _distill_student(sparams, scfg, sfwd, tparams, tcfg, part, data,
                      steps=150, batch=128, dcfg: DS.DistillConfig = DS.DistillConfig()):
     mom = sgd_init(sparams)
     part = jnp.asarray(part)
-
-    @jax.jit
-    def step(sparams, mom, x, y):
-        t_logits, t_feats, _ = cnn.wrn_forward(tparams, tcfg, x)
-        t_part = t_feats[:, part]
-
-        def loss_fn(p):
-            logits, feats, newp = sfwd(p, scfg, x, train=True)
-            return DS.distill_loss(logits, feats, t_logits, t_part, y, dcfg), newp
-
-        (loss, newp), grads = jax.value_and_grad(loss_fn, has_aux=True)(sparams)
-        sparams2, mom2 = sgd_update(sparams, grads, mom)
-        sparams2 = merge_bn_stats(sparams2, newp)   # BN running stats only
-        return sparams2, mom2, loss
-
     for i, (x, y) in enumerate(data.epoch(batch, steps, seed0=50_000)):
-        sparams, mom, _ = step(sparams, mom, jnp.asarray(x), jnp.asarray(y))
+        sparams, mom, _ = _distill_step(sparams, mom, tparams, part,
+                                        jnp.asarray(x), jnp.asarray(y),
+                                        scfg=scfg, sfwd=sfwd, tcfg=tcfg,
+                                        dcfg=dcfg)
     return sparams
 
 

@@ -22,8 +22,9 @@ an unknown shape falls back to the kernel's built-in defaults, so a stale or
 missing table can never change numerics — only speed.
 
 The in-process table is loaded once from ``REPRO_TUNING_TABLE`` (env var)
-or the package-adjacent ``tuning_table.json`` if present; ``set_table`` /
-``reset`` override it for tests and benchmarks.
+when it is set, and is empty otherwise — no file is read that the caller
+did not name; ``set_table`` / ``reset`` override it for tests and
+benchmarks.
 
 Search discipline
 -----------------
@@ -59,8 +60,6 @@ CANDIDATES: Dict[str, Dict[str, Tuple[int, ...]]] = {
 
 # a non-default config must win by this factor to be recorded
 HYSTERESIS = 1.05
-
-_DEFAULT_PATH = pathlib.Path(__file__).with_name("tuning_table.json")
 
 
 def table_key(kernel: str, shape: Sequence[int], dtype) -> str:
@@ -100,13 +99,13 @@ _table: Optional[TuningTable] = None
 
 
 def active_table() -> TuningTable:
-    """The process-wide table ops.py consults: ``REPRO_TUNING_TABLE`` when
-    set, else the package-adjacent ``tuning_table.json``, else empty."""
+    """The process-wide table ops.py consults: the table at
+    ``REPRO_TUNING_TABLE`` when set, else empty (built-in defaults)."""
     global _table
     if _table is None:
-        path = os.environ.get("REPRO_TUNING_TABLE") or _DEFAULT_PATH
+        path = os.environ.get("REPRO_TUNING_TABLE")
         try:
-            _table = TuningTable.load(path)
+            _table = TuningTable.load(path) if path else TuningTable()
         except (OSError, ValueError):
             _table = TuningTable()
     return _table
@@ -188,8 +187,8 @@ def key_quorum_aggregate(portions, weights) -> Tuple[Tuple[int, ...], object]:
 
 def key_coded_decode(shares, dec) -> Tuple[Tuple[int, ...], object]:
     """(B, R, K, F) + shares dtype."""
-    B, R, F = shares.shape
-    return (B, R, int(dec.shape[1]), F), shares.dtype
+    R, B, F = shares.shape
+    return (B, R, int(dec.shape[0]), F), shares.dtype
 
 
 def key_dequant_matmul(x, q) -> Tuple[Tuple[int, ...], object]:

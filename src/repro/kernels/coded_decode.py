@@ -1,8 +1,8 @@
 """Fused coded-share decode: masked pseudo-inverse-weighted gather-matmul.
 
 After an erasure-coded dispatch the source holds the arrived-share tensor
-(B, R, F) — R = K systematic + P parity shares, rows of dead shares
-garbage — and a per-request decode operator ``dec`` (B, K, R) built host-side
+(R, B, F) — R = K systematic + P parity shares, rows of dead shares
+garbage — and a per-request decode operator ``dec`` (K, B, R) built host-side
 from the arrival pattern (identity rows for arrived systematic shares,
 pseudo-inverse rows of the MDS generator for erased ones, zeros for
 unrecoverable slots — see :func:`repro.coding.codes.decode_matrix`). The
@@ -11,7 +11,12 @@ gather over the share axis into one pass, so dead-share rows cost no HBM
 traffic re-reads and the recovered portion tensor never materializes an
 intermediate:
 
-    out (B, K, F)[b, k] = Σ_r  mask[b, r] · dec[b, k, r] · share[b, r] · s_r
+    out (K, B, F)[k, b] = Σ_r  mask[b, r] · dec[k, b, r] · share[r, b] · s_r
+
+The share axis leads and the batch axis sits second-to-last in every
+operand, so each block's last two dims are (batch tile, full width) — the
+layout the TPU lowering requires — and the output is already in the
+(K, B, F) slot-major layout the quorum merge consumes.
 
 Grid (nb, K), both parallel: each program reduces the full (small) share
 axis for one (batch-tile, slot) pair on the VPU — R is a handful of shares,
@@ -31,59 +36,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
-
-def _decode_kernel(scale_ref, x_ref, d_ref, m_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)                     # (bb, R, F)
-    s = scale_ref[...].astype(jnp.float32)                 # (R,)
+def _decode_kernel(x_ref, d_ref, m_ref, s_ref, o_ref):
     # fold mask and dequant scale into the per-request decode weights: one
-    # (bb, R) weight row instead of touching the (bb, R, F) payload twice
-    w = d_ref[:, 0, :] * m_ref[...].astype(jnp.float32) * s[None, :]
-    o_ref[:, 0, :] = jnp.sum(x * w[:, :, None], axis=1)
+    # (bb, R) weight row instead of touching the (R, bb, F) payload twice
+    w = d_ref[...] * m_ref[...].astype(jnp.float32) * s_ref[...]   # (bb, R)
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for r in range(x_ref.shape[0]):
+        acc = acc + w[:, r:r + 1] * x_ref[r].astype(jnp.float32)
+    o_ref[...] = acc
 
 
 def coded_decode(shares: jnp.ndarray, dec: jnp.ndarray, mask: jnp.ndarray,
                  scales: Optional[jnp.ndarray] = None, *,
                  block_batch: int = 128, interpret: bool = False
                  ) -> jnp.ndarray:
-    """shares: (B, R, F) fp32 or int8 arrived-share tensor; dec: (B, K, R)
+    """shares: (R, B, F) fp32 or int8 arrived-share tensor; dec: (K, B, R)
     fp32 per-request decode weights; mask: (B, R) share-arrival mask;
     scales: optional (R,) fp32 per-share dequant scales (required when
-    ``shares`` is int8). Returns the recovered portions (B, K, F) fp32."""
-    B, R, F = shares.shape
-    K = dec.shape[1]
+    ``shares`` is int8). Returns the recovered portions (K, B, F) fp32."""
+    R, B, F = shares.shape
+    K = dec.shape[0]
     if shares.dtype == jnp.int8 and scales is None:
         raise ValueError("int8 shares need per-share fp32 scales")
     if scales is None:
         scales = jnp.ones((R,), jnp.float32)
     if B == 0:
-        return jnp.zeros((0, K, F), jnp.float32)
-    bb = max(1, min(block_batch, B))   # ragged guard: legal grid for any block
+        return jnp.zeros((K, 0, F), jnp.float32)
+    # ragged guard: legal grid for any block; a tile smaller than the batch
+    # is a multiple of 8 rows (the TPU's second-to-last block constraint)
+    bb = max(1, min(block_batch, B))
+    if bb < B:
+        bb = -(-bb // 8) * 8
     pad = (-B) % bb
     if pad:
-        shares = jnp.pad(shares, ((0, pad), (0, 0), (0, 0)))
-        dec = jnp.pad(dec, ((0, pad), (0, 0), (0, 0)))
+        shares = jnp.pad(shares, ((0, 0), (0, pad), (0, 0)))
+        dec = jnp.pad(dec, ((0, 0), (0, pad), (0, 0)))
         mask = jnp.pad(mask, ((0, pad), (0, 0)))
-    nb = shares.shape[0] // bb
+    nb = shares.shape[1] // bb
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, K),
-        in_specs=[
-            pl.BlockSpec((bb, R, F), lambda i, k, *_: (i, 0, 0)),
-            pl.BlockSpec((bb, 1, R), lambda i, k, *_: (i, k, 0)),
-            pl.BlockSpec((bb, R), lambda i, k, *_: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, 1, F), lambda i, k, *_: (i, k, 0)),
-    )
     out = pl.pallas_call(
         _decode_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((shares.shape[0], K, F), jnp.float32),
-        compiler_params=compiler_params(
+        grid=(nb, K),
+        in_specs=[
+            pl.BlockSpec((R, bb, F), lambda i, k: (0, i, 0)),
+            pl.BlockSpec((None, bb, R), lambda i, k: (k, i, 0)),
+            pl.BlockSpec((bb, R), lambda i, k: (i, 0)),
+            pl.BlockSpec((1, R), lambda i, k: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, bb, F), lambda i, k: (k, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, shares.shape[1], F), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(jnp.asarray(scales, jnp.float32), shares,
-      jnp.asarray(dec, jnp.float32), jnp.asarray(mask, jnp.int32))
-    return out[:B]
+    )(shares, jnp.asarray(dec, jnp.float32), jnp.asarray(mask, jnp.int32),
+      jnp.asarray(scales, jnp.float32).reshape(1, R))
+    return out[:, :B]

@@ -22,8 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.compat import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _shard_kernel(x_ref, w_ref, o_ref):
@@ -58,7 +57,7 @@ def coded_matmul(x: jnp.ndarray, shards: jnp.ndarray, *,
         ],
         out_specs=pl.BlockSpec((1, bb, w), lambda s, i: (s, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, x.shape[0], w), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, shards)

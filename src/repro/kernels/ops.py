@@ -110,9 +110,9 @@ def coded_decode(shares, dec, mask, scales=None, *,
                  block_batch: Optional[int] = None,
                  interpret: Optional[bool] = None):
     """Fused masked decode of erasure-coded shares (coding subsystem).
-    shares: (B, R, F) arrived-share tensor (fp32 or int8 with per-share
-    ``scales``); dec: (B, K, R) per-request decode weights; mask: (B, R).
-    Returns the recovered portions (B, K, F).
+    shares: (R, B, F) arrived-share tensor (fp32 or int8 with per-share
+    ``scales``); dec: (K, B, R) per-request decode weights; mask: (B, R).
+    Returns the recovered portions (K, B, F).
     ``block_batch=None`` consults the autotuning table for this shape."""
     shape, dtype = _at.key_coded_decode(shares, dec)
     blocks = _at.resolve("coded_decode", shape, dtype,

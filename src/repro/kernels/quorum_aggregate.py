@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _agg_kernel(mask_ref, scale_ref, p_ref, w_ref, b_ref, o_ref, acc_ref, *,
                 K: int):
@@ -71,7 +69,11 @@ def quorum_aggregate(portions: jnp.ndarray, weights: jnp.ndarray,
         # an empty batch would make bb = 0 and divide the grid by zero;
         # the merge of nothing is the empty logits block
         return jnp.zeros((0, C), jnp.float32)
-    bb = max(1, min(block_batch, B))   # ragged guard: legal grid for any block
+    # ragged guard: legal grid for any block; a tile smaller than the batch
+    # is a multiple of 8 rows (the TPU's second-to-last block constraint)
+    bb = max(1, min(block_batch, B))
+    if bb < B:
+        bb = -(-bb // 8) * 8
     pad = (-B) % bb
     if pad:
         portions = jnp.pad(portions, ((0, 0), (0, pad), (0, 0)))
@@ -93,7 +95,7 @@ def quorum_aggregate(portions: jnp.ndarray, weights: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((portions.shape[1], C), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(mask, jnp.int32), jnp.asarray(scales, jnp.float32),

@@ -81,12 +81,12 @@ def quorum_aggregate_ref(portions, weights, bias, mask,
 
 
 def coded_decode_ref(shares, dec, mask, scales=None) -> jnp.ndarray:
-    """shares: (B, R, F) fp32 or int8; dec: (B, K, R); mask: (B, R);
-    scales: optional (R,) per-share dequant scales. Returns (B, K, F)."""
-    w = dec.astype(jnp.float32) * mask.astype(jnp.float32)[:, None, :]
+    """shares: (R, B, F) fp32 or int8; dec: (K, B, R); mask: (B, R);
+    scales: optional (R,) per-share dequant scales. Returns (K, B, F)."""
+    w = dec.astype(jnp.float32) * mask.astype(jnp.float32)[None, :, :]
     if scales is not None:
         w = w * scales.astype(jnp.float32)[None, None, :]
-    return jnp.einsum("bkr,brf->bkf", w, shares.astype(jnp.float32))
+    return jnp.einsum("kbr,rbf->kbf", w, shares.astype(jnp.float32))
 
 
 def coded_matmul_ref(x, shards) -> jnp.ndarray:

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
                 Q: int, nc: int):
@@ -95,7 +93,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, Bm: jnp.ndarray,
         out_specs=pl.BlockSpec((1, 1, Q, P), lambda b, c: (b, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, nc, Q, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x4, dt3, A.astype(jnp.float32), B4, C4)

@@ -1,6 +1,6 @@
 import os
-# _DRYRUN_HOST_DEVICES lets a caller shrink the forced host-device count
-# (e.g. benchmarks/roofline.py drives --tiny cells in a subprocess with 8)
+# _DRYRUN_HOST_DEVICES shrinks the forced host-device count (e.g. 8 for
+# the --tiny cells that benchmarks/roofline.py reports)
 os.environ["XLA_FLAGS"] = (
     os.environ.get("_DRYRUN_EXTRA_XLA", "")
     + " --xla_force_host_platform_device_count="
@@ -37,8 +37,8 @@ from repro.parallel.sharding import axis_rules
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "results"
 
 # --tiny mode: same shape *kinds* at smoke scale, compiled on a host mesh —
-# lets benchmarks/roofline.py produce a roofline artifact without a 512
-# -device multi-pod sweep
+# gives benchmarks/roofline.py a roofline artifact without a 512-device
+# multi-pod sweep
 TINY_SHAPES = {
     "train_4k": ShapeConfig("train_4k", 256, 8, "train"),
     "prefill_32k": ShapeConfig("prefill_32k", 512, 4, "prefill"),

@@ -156,7 +156,7 @@ def _inv_res_apply(p, x, *, stride, train):
     if p["expand"] is not None:
         h = L.conv2d_apply(p["expand"], h)
     h, newp["bn0"] = L.batchnorm_apply(p["bn0"], h, train=train)
-    h = jax.nn.relu6(h) if hasattr(jax.nn, "relu6") else jnp.clip(h, 0, 6)
+    h = jax.nn.relu6(h)
     h = L.conv2d_apply(p["dw"], h, stride=stride, groups=h.shape[-1])
     h, newp["bn1"] = L.batchnorm_apply(p["bn1"], h, train=train)
     h = jnp.clip(h, 0, 6)

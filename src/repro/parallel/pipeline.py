@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
                    stage_params: Any, x: jnp.ndarray, *, mesh: Mesh,
@@ -69,11 +67,11 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         return outs
 
     xs = x.reshape(M, mb, *x.shape[1:])
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),      # params stage-sharded, data replicated
         out_specs=P(),
-        check=False)
+        check_vma=False)
     outs = fn(stage_params, xs)
     return outs.reshape(B, *x.shape[1:])
 

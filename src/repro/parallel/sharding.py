@@ -58,16 +58,8 @@ def axis_rules(rules: Dict[str, MeshAxes], mesh: Optional[Mesh] = None):
 
 
 def current_mesh() -> Optional[Mesh]:
-    st = _state()
-    if st.mesh is not None:
-        return st.mesh
-    try:
-        env_mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh  # type: ignore
-        if env_mesh and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
+    """The mesh that the innermost ``axis_rules`` context names, if any."""
+    return _state().mesh
 
 
 def resolve_spec(logical: Sequence[Optional[str]],

@@ -67,6 +67,7 @@ track fan-out futures and count cancelled in-flight shares.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -324,8 +325,7 @@ class QuorumServer:
         """ONE compiled program for the whole micro-batch: (optional int8
         dequant →) vmapped portion forward over the stacked K axis →
         device-side per-row arrived mask → fused quorum_aggregate merge.
-        No host round-trip between forward and merge; the per-call mask
-        buffers are donated so XLA reuses them as scratch."""
+        No host round-trip between forward and merge."""
         apply = self.fused.apply
         pre = self.fused.pre
         int8 = self.quantize == "int8"
@@ -349,11 +349,7 @@ class QuorumServer:
             return _qa.quorum_aggregate(portions, fc_w, fc_b, any_mask,
                                         fc_scales, interpret=interpret)
 
-        # donating on CPU only triggers a "not implemented" warning
-        donate = (("row_mask", "any_mask")
-                  if jax.default_backend() != "cpu" else ())
-        return jax.jit(step, static_argnames=("masked",),
-                       donate_argnames=donate)
+        return jax.jit(step, static_argnames=("masked",))
 
     def _invalidate_fused(self) -> None:
         self._fused_stacked = None
@@ -425,15 +421,12 @@ class QuorumServer:
             portions = jax.vmap(apply, in_axes=(0, None))(params, x)
             parity = jnp.einsum("pk,kbf->pbf", enc, portions)
             shares = jnp.concatenate([portions, parity], axis=0)
-            decoded = _cd.coded_decode(jnp.transpose(shares, (1, 0, 2)),
-                                       dec, share_mask, interpret=interpret)
-            return _qa.quorum_aggregate(jnp.transpose(decoded, (1, 0, 2)),
-                                        fc_w, fc_b, any_mask, fc_scales,
-                                        interpret=interpret)
+            decoded = _cd.coded_decode(shares, dec, share_mask,
+                                       interpret=interpret)
+            return _qa.quorum_aggregate(decoded, fc_w, fc_b, any_mask,
+                                        fc_scales, interpret=interpret)
 
-        donate = (("dec", "share_mask", "any_mask")
-                  if jax.default_backend() != "cpu" else ())
-        return jax.jit(step, donate_argnames=donate)
+        return jax.jit(step)
 
     def _build_fused_step_compute(self) -> Callable:
         """The compute-coded megastep: vmapped portion forward → per-coded
@@ -451,6 +444,7 @@ class QuorumServer:
         rtc = self._compute_runtime(self.ir)
         entries = [(e.slot, e.k, jnp.asarray(e.G[e.k:], jnp.float32))
                    for e in rtc.entries]
+        decode = functools.partial(_cd.coded_decode, interpret=interpret)
 
         def step(stacked, x, decs, masks, row_mask, any_mask, fc_w,
                  fc_scales, fc_b):
@@ -460,25 +454,15 @@ class QuorumServer:
             portions = jax.vmap(apply, in_axes=(0, None))(params, x)
             rec = {}
             for (slot, k, Gpar), dec, m in zip(entries, decs, masks):
-                y = portions[slot]                           # (B, Dk)
-                F = y.shape[1]
-                w = -(-F // k)
-                ypad = jnp.pad(y, ((0, 0), (0, k * w - F)))
-                blocks = ypad.reshape(-1, k, w)              # (B, k, w)
-                par = jnp.einsum("rk,bkw->brw", Gpar, blocks)
-                shares = jnp.concatenate([blocks, par], axis=1)
-                decoded = _cd.coded_decode(shares, dec, m,
-                                           interpret=interpret)
-                rec[slot] = decoded.reshape(-1, k * w)[:, :F]
+                rec[slot] = _compute_decode(portions[slot], Gpar, k, dec, m,
+                                            decode)
             portions = jnp.stack([rec.get(s, portions[s])
                                   for s in range(portions.shape[0])])
             portions = portions * row_mask.T[:, :, None].astype(portions.dtype)
             return _qa.quorum_aggregate(portions, fc_w, fc_b, any_mask,
                                         fc_scales, interpret=interpret)
 
-        donate = (("row_mask", "any_mask")
-                  if jax.default_backend() != "cpu" else ())
-        return jax.jit(step, donate_argnames=donate)
+        return jax.jit(step)
 
     # -- serving -------------------------------------------------------------
 
@@ -629,8 +613,9 @@ class QuorumServer:
             # host-built per-request decode operators (memoized pinv per
             # arrival pattern), expanded to rows; everything else happens
             # inside the compiled program
-            dec_rows = np.repeat(rt.decode_weights(share_arrived),
-                                 sizes, axis=0)              # (B, K, R_sh)
+            dec_rows = np.repeat(
+                rt.decode_weights(share_arrived).transpose(1, 0, 2),
+                sizes, axis=1)                               # (K, B, R_sh)
             mask_rows = np.repeat(share_arrived, sizes, axis=0)
             if fastpath:
                 logits = step_coded(stacked, x_all, dec_rows, mask_rows,
@@ -645,10 +630,9 @@ class QuorumServer:
                                        for kslot in range(Kp)])  # (K, B, Dk)
                 parity = jnp.einsum("pk,kbf->pbf", rt.enc_device, stacked_p)
                 shares = jnp.concatenate([stacked_p, parity], axis=0)
-                decoded = K.coded_decode(jnp.transpose(shares, (1, 0, 2)),
-                                         dec_rows, mask_rows)
+                decoded = K.coded_decode(shares, dec_rows, mask_rows)
                 logits = K.quorum_aggregate(
-                    jnp.transpose(decoded, (1, 0, 2)), fc_weights, fc_bias,
+                    decoded, fc_weights, fc_bias,
                     jnp.asarray(any_arrived, jnp.int32))
             return self._package(xs, R, sizes, offs, logits, arrived,
                                  latency, alive, arrays,
@@ -659,7 +643,8 @@ class QuorumServer:
             # per chosen-shard pattern) expanded to rows; the shard products
             # + parity emulation + decode + merge stay in ONE program
             decs, masks = rtc.decode_weights(share_t)
-            dec_rows = tuple(np.repeat(d, sizes, axis=0) for d in decs)
+            dec_rows = tuple(np.repeat(d.transpose(1, 0, 2), sizes, axis=1)
+                             for d in decs)                 # (k, B, n) each
             mask_rows = tuple(np.repeat(m, sizes, axis=0) for m in masks)
             row_arr = np.repeat(arrived, sizes, axis=0)
             if fastpath:
@@ -673,17 +658,9 @@ class QuorumServer:
                 x_dev = jnp.asarray(x_all)
                 portions = [jitted[kslot](x_dev) for kslot in range(Kp)]
                 for e, dec, m in zip(rtc.entries, dec_rows, mask_rows):
-                    y = portions[e.slot]                        # (B, Dk)
-                    F = int(y.shape[1])
-                    w = -(-F // e.k)
-                    ypad = jnp.pad(y, ((0, 0), (0, e.k * w - F)))
-                    blocks = ypad.reshape(-1, e.k, w)
-                    par = jnp.einsum("rk,bkw->brw",
-                                     jnp.asarray(e.G[e.k:], jnp.float32),
-                                     blocks)
-                    shares = jnp.concatenate([blocks, par], axis=1)
-                    decoded = K.coded_decode(shares, dec, m)
-                    portions[e.slot] = decoded.reshape(-1, e.k * w)[:, :F]
+                    portions[e.slot] = _compute_decode(
+                        portions[e.slot], jnp.asarray(e.G[e.k:], jnp.float32),
+                        e.k, dec, m, K.coded_decode)
                 stacked_p = jnp.stack(portions)        # (K, B, Dk)
                 stacked_p = stacked_p * jnp.asarray(
                     row_arr.T[:, :, None], stacked_p.dtype)
@@ -1065,6 +1042,23 @@ class QuorumServer:
                 used = used | cs.parity_member.any(0)
             return [devs[n] for n in np.flatnonzero(used)]
         return [d for g in self.plan.groups for d in g.devices]
+
+
+def _compute_decode(y: jnp.ndarray, Gpar: jnp.ndarray, k: int,
+                    dec, mask, decode: Callable) -> jnp.ndarray:
+    """One compute-coded slot: split its (B, F) output column-wise into k
+    data blocks, emulate the r pre-encoded parity shards (``Gpar`` (r, k)),
+    and recover the k blocks with ``decode(shares, dec, mask)`` — a
+    coded_decode entry point — from the slot's first-k decode weights
+    ``dec`` (k, B, k + r) and arrival ``mask`` (B, k + r). Shares are laid
+    out (k + r, B, w), the kernel's share-major layout."""
+    F = y.shape[1]
+    w = -(-F // k)
+    blocks = jnp.pad(y, ((0, 0), (0, k * w - F))).reshape(-1, k, w)
+    blocks = jnp.transpose(blocks, (1, 0, 2))                  # (k, B, w)
+    par = jnp.einsum("rk,kbw->rbw", Gpar, blocks)
+    decoded = decode(jnp.concatenate([blocks, par], axis=0), dec, mask)
+    return jnp.transpose(decoded, (1, 0, 2)).reshape(-1, k * w)[:, :F]
 
 
 def _padded_portion(fn: Callable, width: int) -> Callable:

@@ -586,8 +586,8 @@ def test_serving_kernels_ragged_block_batch(kernel):
         out = ops.quorum_aggregate(p, w, b, m, block_batch=16)
         exp = ref.quorum_aggregate_ref(p, w, b, m)
     else:
-        sh = jnp.asarray(rng.normal(size=(B, 5, 8)).astype(np.float32))
-        dec = jnp.asarray(rng.normal(size=(B, 3, 5)).astype(np.float32))
+        sh = jnp.asarray(rng.normal(size=(5, B, 8)).astype(np.float32))
+        dec = jnp.asarray(rng.normal(size=(3, B, 5)).astype(np.float32))
         m = jnp.ones((B, 5), jnp.float32)
         out = ops.coded_decode(sh, dec, m, block_batch=16)
         exp = ref.coded_decode_ref(sh, dec, m)

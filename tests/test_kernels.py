@@ -120,8 +120,8 @@ def test_quorum_all_failed_is_bias():
                                      (1, 9, 7, 32)])
 def test_coded_decode(B, R, K, F):
     ks = jax.random.split(jax.random.key(7), 3)
-    sh = jax.random.normal(ks[0], (B, R, F))
-    dec = jax.random.normal(ks[1], (B, K, R))
+    sh = jax.random.normal(ks[0], (R, B, F))
+    dec = jax.random.normal(ks[1], (K, B, R))
     mask = (jax.random.uniform(ks[2], (B, R)) > 0.3).astype(jnp.int32)
     out = ops.coded_decode(sh, dec, mask, block_batch=32)
     exp = ref.coded_decode_ref(sh, dec, mask)
@@ -132,8 +132,8 @@ def test_coded_decode(B, R, K, F):
 @pytest.mark.parametrize("B,R,K,F", [(16, 5, 3, 16), (64, 4, 4, 8)])
 def test_coded_decode_int8_shares(B, R, K, F):
     ks = jax.random.split(jax.random.key(8), 3)
-    sh = jax.random.randint(ks[0], (B, R, F), -127, 128, jnp.int8)
-    dec = jax.random.normal(ks[1], (B, K, R))
+    sh = jax.random.randint(ks[0], (R, B, F), -127, 128, jnp.int8)
+    dec = jax.random.normal(ks[1], (K, B, R))
     mask = (jax.random.uniform(ks[2], (B, R)) > 0.4).astype(jnp.int32)
     scales = jnp.abs(jax.random.normal(jax.random.key(9), (R,))) + 0.1
     out = ops.coded_decode(sh, dec, mask, scales, block_batch=32)
@@ -144,16 +144,16 @@ def test_coded_decode_int8_shares(B, R, K, F):
 
 def test_coded_decode_int8_needs_scales():
     with pytest.raises(ValueError, match="scales"):
-        ops.coded_decode(jnp.zeros((2, 3, 4), jnp.int8),
+        ops.coded_decode(jnp.zeros((3, 2, 4), jnp.int8),
                          jnp.zeros((2, 2, 3)), jnp.ones((2, 3), jnp.int32))
 
 
 def test_coded_decode_dead_shares_contribute_nothing():
     """An all-dead mask yields exactly zero regardless of share payloads."""
-    sh = jnp.ones((4, 3, 8)) * 1e6
-    dec = jnp.ones((4, 2, 3))
+    sh = jnp.ones((3, 4, 8)) * 1e6
+    dec = jnp.ones((2, 4, 3))
     out = ops.coded_decode(sh, dec, jnp.zeros((4, 3), jnp.int32))
-    np.testing.assert_array_equal(np.asarray(out), np.zeros((4, 2, 8)))
+    np.testing.assert_array_equal(np.asarray(out), np.zeros((2, 4, 8)))
 
 
 @pytest.mark.parametrize("N,E,k", [(128, 8, 2), (1000, 64, 6), (77, 16, 4)])

@@ -91,8 +91,7 @@ def test_make_rules_seq_shard_for_long_context():
     from repro.configs.base import SHAPES
     from repro.launch import steps as ST
     cfg = get_config("mamba2-130m")
-    from repro.compat import abstract_mesh
-    mesh = abstract_mesh((1, 4, 1), ("pod", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((1, 4, 1), ("pod", "data", "model"))
     rules = ST.make_rules(cfg, SHAPES["long_500k"], mesh)
     assert rules["batch"] is None           # batch 1 can't fill DP
     assert rules["seq_shard"] == "data"     # SP takes the axis instead
