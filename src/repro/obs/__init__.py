@@ -16,7 +16,10 @@ Three small, dependency-free layers that every runtime level shares:
 
 Instrumentation is nullable end to end: with no :class:`Tracer` attached
 the runtime is bit-identical to an uninstrumented build (pinned by
-``tests/test_obs.py``). See ``docs/observability.md``.
+``tests/test_obs.py``). The :class:`Tracer` is for simulated runs, on the
+virtual clock. Real-clock spans of the engine's and the server's host
+phases (``engine.*``, ``server.*``) go to the JAX profiler instead, beside
+the device ops of the same trace. See ``docs/observability.md``.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                P2Quantile)

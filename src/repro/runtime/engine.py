@@ -41,6 +41,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.simulator import FailureModel
 from repro.obs.stats import percentile, throughput
@@ -89,6 +90,7 @@ class BatchRecord:
     rows: int
     plan_epoch: int
     service_s: float
+    pad_rows: int = 0               # filler rows added by row bucketing
 
 
 @dataclasses.dataclass
@@ -318,14 +320,15 @@ class ServingEngine:
             self.metrics.counter("requests_shed", **self.metric_labels).inc()
 
     def _trace_dispatch(self, now: float, reqs: List[RequestRecord],
-                        bid: int, done_t: float, rows: int,
+                        bid: int, done_t: float, rows: int, pad_rows: int,
                         service: float) -> None:
         """Close every dispatched request's batch-wait, record its service
         span and terminal outcome, and record the batch span itself."""
         tr = self.tracer
         tr.complete("batch", f"{self.trace_name}batches", now, done_t,
                     bid=bid, n_requests=len(reqs), rows=rows,
-                    plan_epoch=self.plan_epoch, service_s=service)
+                    pad_rows=pad_rows, plan_epoch=self.plan_epoch,
+                    service_s=service)
         for r in reqs:
             spans = self._req_spans.pop(r.rid, None)
             if spans is None:
@@ -398,25 +401,41 @@ class ServingEngine:
         plans — the ``(arrival_time, future_index)`` share events to put on
         the virtual clock (one per in-flight share of every fan-out future
         issued for this batch's requests)."""
-        self._apply_control(now)
-        xs = [self._input(r.size) for r in reqs]
         rows = sum(r.size for r in reqs)
-        pad_rows = 0
-        if self.cfg.bucket_rows and rows:
-            bucket = 1 << (rows - 1).bit_length()
-            pad_rows = bucket - rows
-            if pad_rows:
-                xs = xs + [self._input(pad_rows)]   # filler request, dropped
-        t0 = time.perf_counter()
-        results = self.server.serve_batch(xs, rng=self._batch_rng(bid))
-        if self.cfg.service_model is None and results:
-            # serve_batch returns without waiting for the device (the
-            # logits sync is deferred to ServeResult access). In
-            # measured-wall mode the device time IS the service time, so
-            # block inside the timed region; in modelled mode skip the
-            # sync — the next micro-batch overlaps the in-flight one
-            results[0].block_until_ready()
-        wall = time.perf_counter() - t0
+        pad_rows = ((1 << (rows - 1).bit_length()) - rows
+                    if self.cfg.bucket_rows and rows else 0)
+        # real-clock spans for the profiler (no-ops while it is off); the
+        # server's own phases nest under this batch's span
+        with TraceAnnotation("engine.batch", bid=bid, n_requests=len(reqs),
+                             rows=rows, pad_rows=pad_rows) as span:
+            with TraceAnnotation("engine.control"):
+                self._apply_control(now)
+            span.set_metadata(plan_epoch=self.plan_epoch)
+            with TraceAnnotation("engine.inputs"):
+                xs = [self._input(r.size) for r in reqs]
+                if pad_rows:
+                    xs.append(self._input(pad_rows))  # filler, dropped
+            t0 = time.perf_counter()
+            results = self.server.serve_batch(xs, rng=self._batch_rng(bid))
+            if self.cfg.service_model is None and results:
+                # serve_batch returns without waiting for the device (the
+                # logits sync is deferred to ServeResult access). In
+                # measured-wall mode the device time IS the service time,
+                # so block inside the timed region; in modelled mode skip
+                # the sync — the next micro-batch overlaps the in-flight one
+                with TraceAnnotation("engine.device_wait"):
+                    results[0].block_until_ready()
+            wall = time.perf_counter() - t0
+            with TraceAnnotation("engine.record"):
+                return self._record_batch(now, reqs, bid, rows, pad_rows,
+                                          results, wall)
+
+    def _record_batch(self, now: float, reqs: List[RequestRecord], bid: int,
+                      rows: int, pad_rows: int, results, wall: float
+                      ) -> Tuple[float, BatchRecord, List[Tuple[float, int]]]:
+        """Fold a served micro-batch into its requests' records, the coded
+        fan-out futures, the tracer and the metrics; returns what
+        :meth:`_dispatch` returns."""
         if self.cfg.service_model is not None:
             alpha, beta = self.cfg.service_model
             service = alpha + beta * rows
@@ -458,9 +477,10 @@ class ServingEngine:
                 share_events.extend(
                     (now + float(t), idx) for t in t_sh[finite])
         batch = BatchRecord(bid, now, done_t, len(reqs), rows,
-                            self.plan_epoch, service)
+                            self.plan_epoch, service, pad_rows)
         if self.tracer is not None:
-            self._trace_dispatch(now, reqs, bid, done_t, rows, service)
+            self._trace_dispatch(now, reqs, bid, done_t, rows, pad_rows,
+                                 service)
         if self.metrics is not None:
             self._record_metrics(reqs)
         return done_t, batch, share_events

@@ -68,12 +68,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.grouping import Device
 from repro.core.plan_ir import PlanIR
@@ -275,7 +275,7 @@ class QuorumServer:
         for i, fn in enumerate(self._jitted):
             if fn is None:
                 self._jitted[i] = jax.jit(_padded_portion(
-                    self.portion_fns[i], Dk))
+                    self.portion_fns[i], Dk, i))
         return self._jitted
 
     @property
@@ -474,25 +474,6 @@ class QuorumServer:
     def serve_batch(self, xs: Sequence[jnp.ndarray], *,
                     rng: Optional[np.random.Generator] = None
                     ) -> List[ServeResult]:
-        """Serve R stacked requests — see :meth:`_serve_batch` for the
-        full contract. This thin shim adds the optional ``serve_batch``
-        trace span (dispatch wall time, request/row counts) when a tracer
-        is wired; with no tracer it is a tail call into the real path."""
-        if self.tracer is None:
-            return self._serve_batch(xs, rng=rng)
-        t0 = time.perf_counter()
-        out = self._serve_batch(xs, rng=rng)
-        t = self.tracer.now
-        self.tracer.complete(
-            "serve_batch", f"{self.trace_name}server", t, t,
-            requests=len(xs),
-            rows=int(sum(int(x.shape[0]) for x in xs)),
-            wall_us=(time.perf_counter() - t0) * 1e6)
-        return out
-
-    def _serve_batch(self, xs: Sequence[jnp.ndarray], *,
-                     rng: Optional[np.random.Generator] = None
-                     ) -> List[ServeResult]:
         """Serve R stacked requests. On the fused fast path this is ONE
         jitted dispatch (stacked portion forwards + device-side masking +
         quorum merge in a single compiled program); the legacy flag path
@@ -511,7 +492,13 @@ class QuorumServer:
         before any compute, and migration installs fresh objects instead of
         mutating shared ones — an in-flight batch finishes on the plan it
         was dispatched under while queued requests pick up the migrated
-        plan."""
+        plan.
+
+        Each host phase runs under a ``server.*`` profiler span
+        (``jax.profiler.TraceAnnotation``; a no-op while no trace is being
+        recorded): ``draw``, ``stack``, ``slot_forward`` / ``slot_mask``
+        (stat ``slot``), ``decode_ops``, ``merge`` or ``fused_step``, and
+        ``package``. None of them waits for the device."""
         R = len(xs)
         if R == 0:
             return []
@@ -542,13 +529,16 @@ class QuorumServer:
 
         sizes = [int(x.shape[0]) for x in xs]
         offs = np.concatenate([[0], np.cumsum(sizes)])
-        # stack requests in numpy: an eager jnp.concatenate compiles one XLA
-        # program per DISTINCT tuple of request shapes, which under
-        # continuous batching (heterogeneous sizes) means a ~20ms recompile
-        # on almost every micro-batch. The stack stays numpy — the jit
-        # boundary devices it once, on the fast path
-        x_all = xs[0] if R == 1 else np.concatenate(
-            [np.asarray(x) for x in xs], axis=0)
+        with TraceAnnotation("server.stack"):
+            # stack requests in numpy: an eager jnp.concatenate compiles one
+            # XLA program per DISTINCT tuple of request shapes, which under
+            # continuous batching (heterogeneous sizes) means a ~20ms
+            # recompile on almost every micro-batch. On the fast path the
+            # numpy stack crosses the jit boundary directly; the per-slot
+            # loop puts it on the device once for its K calls
+            x_all = xs[0] if R == 1 else np.concatenate(
+                [np.asarray(x) for x in xs], axis=0)
+            x_dev = None if fastpath else jnp.asarray(x_all)
         B = int(offs[-1])
 
         # a scenario deadline can only TIGHTEN the server's own SLO deadline
@@ -557,32 +547,36 @@ class QuorumServer:
         scenario_deadline = getattr(failure, "deadline", None)
         if scenario_deadline is not None:
             deadline = min(deadline, scenario_deadline)
-        # a fully deterministic failure model (no forced set, no crash, no
-        # outage channel) draws nothing and always yields the same per-row
-        # outcome for a given (plan, deadline) — memoize it instead of
-        # re-sampling and re-reducing per micro-batch (this path is the
-        # failure-free hot loop; the generator is untouched either way, so
-        # the cached rows are bit-identical to the computed ones)
         share_arrived = share_t = None
-        if (type(failure) is FailureModel and not failure.forced_failures
-                and failure.crash_prob == 0 and not failure.outages):
-            alive1, arrived1, lat1, share1, share_t1 = (
-                self._deterministic_outcome(arrays, deadline))
-            alive = np.broadcast_to(alive1, (R, alive1.shape[0]))
-            arrived = np.broadcast_to(arrived1, (R, arrived1.shape[0]))
-            latency = np.broadcast_to(lat1, (R,))
-            if share1 is not None:
-                share_arrived = np.broadcast_to(share1, (R, share1.shape[0]))
-                share_t = np.broadcast_to(share_t1, (R, share_t1.shape[0]))
-        else:
-            alive, delay = failure.sample(rng, arrays, R)
-            if rt is not None or rtc is not None:
-                _, arrived, latency, share_arrived, share_t = (
-                    reduce_trials_coded(arrays, alive, delay, deadline,
-                                        return_share_times=True))
+        with TraceAnnotation("server.draw"):
+            # a fully deterministic failure model (no forced set, no crash,
+            # no outage channel) draws nothing and always yields the same
+            # per-row outcome for a given (plan, deadline) — memoize it
+            # instead of re-sampling and re-reducing per micro-batch (this
+            # path is the failure-free hot loop; the generator is untouched
+            # either way, so the cached rows are bit-identical to the
+            # computed ones)
+            if (type(failure) is FailureModel and not failure.forced_failures
+                    and failure.crash_prob == 0 and not failure.outages):
+                alive1, arrived1, lat1, share1, share_t1 = (
+                    self._deterministic_outcome(arrays, deadline))
+                alive = np.broadcast_to(alive1, (R, alive1.shape[0]))
+                arrived = np.broadcast_to(arrived1, (R, arrived1.shape[0]))
+                latency = np.broadcast_to(lat1, (R,))
+                if share1 is not None:
+                    share_arrived = np.broadcast_to(share1,
+                                                    (R, share1.shape[0]))
+                    share_t = np.broadcast_to(share_t1,
+                                              (R, share_t1.shape[0]))
             else:
-                _, arrived, latency = reduce_trials(arrays, alive, delay,
-                                                    deadline)
+                alive, delay = failure.sample(rng, arrays, R)
+                if rt is not None or rtc is not None:
+                    _, arrived, latency, share_arrived, share_t = (
+                        reduce_trials_coded(arrays, alive, delay, deadline,
+                                            return_share_times=True))
+                else:
+                    _, arrived, latency = reduce_trials(arrays, alive, delay,
+                                                        deadline)
 
         # per-sample row mask: request r's rows of portion k are zeroed when
         # k missed r's quorum (linear merge ⇒ exact per-request masking).
@@ -609,94 +603,106 @@ class QuorumServer:
                 fc_w, fc_scales = fc_q.q, fc_q.scale
             else:
                 fc_w, fc_scales = fc_weights, None
+
+        def forward(kslot):
+            with TraceAnnotation("server.slot_forward", slot=kslot):
+                return jitted[kslot](x_dev)   # padded to Dk inside the jit
+
         if decode_needed:
             # host-built per-request decode operators (memoized pinv per
             # arrival pattern), expanded to rows; everything else happens
             # inside the compiled program
-            dec_rows = np.repeat(
-                rt.decode_weights(share_arrived).transpose(1, 0, 2),
-                sizes, axis=1)                               # (K, B, R_sh)
-            mask_rows = np.repeat(share_arrived, sizes, axis=0)
+            with TraceAnnotation("server.decode_ops"):
+                dec_rows = np.repeat(
+                    rt.decode_weights(share_arrived).transpose(1, 0, 2),
+                    sizes, axis=1)                           # (K, B, R_sh)
+                mask_rows = np.repeat(share_arrived, sizes, axis=0)
             if fastpath:
-                logits = step_coded(stacked, x_all, dec_rows, mask_rows,
-                                    any_arrived, rt.enc_device, fc_w,
-                                    fc_scales, fc_bias)
+                with TraceAnnotation("server.fused_step"):
+                    logits = step_coded(stacked, x_all, dec_rows, mask_rows,
+                                        any_arrived, rt.enc_device, fc_w,
+                                        fc_scales, fc_bias)
             else:
                 # the oracle loop: every portion is computed (the parity
                 # emulation combines them), then the SAME encode → decode →
                 # merge math runs through the jitted ops wrappers
-                x_dev = jnp.asarray(x_all)
-                stacked_p = jnp.stack([jitted[kslot](x_dev)
-                                       for kslot in range(Kp)])  # (K, B, Dk)
-                parity = jnp.einsum("pk,kbf->pbf", rt.enc_device, stacked_p)
-                shares = jnp.concatenate([stacked_p, parity], axis=0)
-                decoded = K.coded_decode(shares, dec_rows, mask_rows)
-                logits = K.quorum_aggregate(
-                    decoded, fc_weights, fc_bias,
-                    jnp.asarray(any_arrived, jnp.int32))
-            return self._package(xs, R, sizes, offs, logits, arrived,
-                                 latency, alive, arrays,
-                                 knowledge_gap=knowledge_gap,
-                                 share_t=share_t)
-        if compute_decode:
+                portions = [forward(kslot) for kslot in range(Kp)]
+                with TraceAnnotation("server.merge"):
+                    stacked_p = jnp.stack(portions)          # (K, B, Dk)
+                    parity = jnp.einsum("pk,kbf->pbf", rt.enc_device,
+                                        stacked_p)
+                    shares = jnp.concatenate([stacked_p, parity], axis=0)
+                    decoded = K.coded_decode(shares, dec_rows, mask_rows)
+                    logits = K.quorum_aggregate(
+                        decoded, fc_weights, fc_bias,
+                        jnp.asarray(any_arrived, jnp.int32))
+        elif compute_decode:
             # host side: per-trial first-k decode operators (memoized pinv
             # per chosen-shard pattern) expanded to rows; the shard products
             # + parity emulation + decode + merge stay in ONE program
-            decs, masks = rtc.decode_weights(share_t)
-            dec_rows = tuple(np.repeat(d.transpose(1, 0, 2), sizes, axis=1)
-                             for d in decs)                 # (k, B, n) each
-            mask_rows = tuple(np.repeat(m, sizes, axis=0) for m in masks)
-            row_arr = np.repeat(arrived, sizes, axis=0)
+            with TraceAnnotation("server.decode_ops"):
+                decs, masks = rtc.decode_weights(share_t)
+                dec_rows = tuple(np.repeat(d.transpose(1, 0, 2), sizes,
+                                           axis=1)
+                                 for d in decs)             # (k, B, n) each
+                mask_rows = tuple(np.repeat(m, sizes, axis=0) for m in masks)
+                row_arr = np.repeat(arrived, sizes, axis=0)
             if fastpath:
-                logits = step_compute(stacked, x_all, dec_rows, mask_rows,
-                                      row_arr, any_arrived, fc_w, fc_scales,
-                                      fc_bias)
+                with TraceAnnotation("server.fused_step"):
+                    logits = step_compute(stacked, x_all, dec_rows,
+                                          mask_rows, row_arr, any_arrived,
+                                          fc_w, fc_scales, fc_bias)
             else:
                 # the oracle loop: full portion forwards, then the SAME
                 # shard-split → parity → first-k decode math through the
                 # jitted ops wrappers
-                x_dev = jnp.asarray(x_all)
-                portions = [jitted[kslot](x_dev) for kslot in range(Kp)]
-                for e, dec, m in zip(rtc.entries, dec_rows, mask_rows):
-                    portions[e.slot] = _compute_decode(
-                        portions[e.slot], jnp.asarray(e.G[e.k:], jnp.float32),
-                        e.k, dec, m, K.coded_decode)
-                stacked_p = jnp.stack(portions)        # (K, B, Dk)
-                stacked_p = stacked_p * jnp.asarray(
-                    row_arr.T[:, :, None], stacked_p.dtype)
-                logits = K.quorum_aggregate(
-                    stacked_p, fc_weights, fc_bias,
-                    jnp.asarray(any_arrived, jnp.int32))
+                portions = [forward(kslot) for kslot in range(Kp)]
+                with TraceAnnotation("server.merge"):
+                    for e, dec, m in zip(rtc.entries, dec_rows, mask_rows):
+                        portions[e.slot] = _compute_decode(
+                            portions[e.slot],
+                            jnp.asarray(e.G[e.k:], jnp.float32), e.k, dec, m,
+                            K.coded_decode)
+                    stacked_p = jnp.stack(portions)        # (K, B, Dk)
+                    stacked_p = stacked_p * jnp.asarray(
+                        row_arr.T[:, :, None], stacked_p.dtype)
+                    logits = K.quorum_aggregate(
+                        stacked_p, fc_weights, fc_bias,
+                        jnp.asarray(any_arrived, jnp.int32))
+        else:
+            row_arrived = None if clean else np.repeat(arrived, sizes, axis=0)
+            if fastpath:
+                # numpy operands cross the jit boundary directly (fast-path
+                # device_put) — no eager conversions before the single
+                # dispatch
+                with TraceAnnotation("server.fused_step"):
+                    logits = step(stacked, x_all, row_arrived, any_arrived,
+                                  fc_w, fc_scales, fc_bias,
+                                  masked=not clean)
+            else:
+                Dk = fc_weights.shape[1]
+                portions = []
+                for kslot in range(Kp):
+                    if not any_arrived[kslot]:
+                        with TraceAnnotation("server.slot_mask", slot=kslot):
+                            portions.append(jnp.zeros((B, Dk), jnp.float32))
+                        continue
+                    p = forward(kslot)
+                    if not clean and not row_arrived[:, kslot].all():
+                        with TraceAnnotation("server.slot_mask", slot=kslot):
+                            p = p * jnp.asarray(row_arrived[:, kslot, None],
+                                                p.dtype)
+                    portions.append(p)
+                with TraceAnnotation("server.merge"):
+                    stacked_p = jnp.stack(portions)        # (K, B, Dk)
+                    logits = K.quorum_aggregate(
+                        stacked_p, fc_weights, fc_bias,
+                        jnp.asarray(any_arrived, jnp.int32))
+        with TraceAnnotation("server.package"):
             return self._package(xs, R, sizes, offs, logits, arrived,
                                  latency, alive, arrays,
                                  knowledge_gap=knowledge_gap,
                                  share_t=share_t)
-        row_arrived = None if clean else np.repeat(arrived, sizes, axis=0)
-
-        if fastpath:
-            # numpy operands cross the jit boundary directly (fast-path
-            # device_put) — no eager conversions before the single dispatch
-            logits = step(stacked, x_all, row_arrived, any_arrived,
-                          fc_w, fc_scales, fc_bias, masked=not clean)
-        else:
-            Dk = fc_weights.shape[1]
-            x_dev = jnp.asarray(x_all)     # one host→device put for K calls
-            portions = []
-            for kslot in range(Kp):
-                if not any_arrived[kslot]:
-                    portions.append(jnp.zeros((B, Dk), jnp.float32))
-                    continue
-                p = jitted[kslot](x_dev)       # padded to Dk inside the jit
-                if not clean and not row_arrived[:, kslot].all():
-                    p = p * jnp.asarray(row_arrived[:, kslot, None], p.dtype)
-                portions.append(p)
-            stacked_p = jnp.stack(portions)        # (K, B, Dk)
-            logits = K.quorum_aggregate(
-                stacked_p, fc_weights, fc_bias,
-                jnp.asarray(any_arrived, jnp.int32))
-        return self._package(xs, R, sizes, offs, logits, arrived, latency,
-                             alive, arrays, knowledge_gap=knowledge_gap,
-                             share_t=share_t)
 
     def _package(self, xs, R, sizes, offs, logits, arrived, latency, alive,
                  arrays, *, knowledge_gap: Optional[bool] = None,
@@ -1061,12 +1067,16 @@ def _compute_decode(y: jnp.ndarray, Gpar: jnp.ndarray, k: int,
     return jnp.transpose(decoded, (1, 0, 2)).reshape(-1, k * w)[:, :F]
 
 
-def _padded_portion(fn: Callable, width: int) -> Callable:
+def _padded_portion(fn: Callable, width: int, slot: int) -> Callable:
+    """Slot ``slot``'s forward padded to ``width`` features. Its jitted
+    program is named ``jit_padded_s<slot>``, so a device trace tells the
+    slots apart (a slot a migration reuses keeps its old name)."""
     def padded(x):
         p = fn(x)
         if p.shape[-1] < width:
             p = jnp.pad(p, ((0, 0), (0, width - p.shape[-1])))
         return p
+    padded.__name__ = padded.__qualname__ = f"padded_s{slot}"
     return padded
 
 

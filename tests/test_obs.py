@@ -236,9 +236,18 @@ def test_chaos_run_span_invariants():
         assert sp.contains(bump)
         assert sp.attrs["epoch"] == bump.attrs["epoch"]
 
-    # chaos instants + serve_batch wall spans + migrate instants landed
+    # chaos instants + one batch span per record + migrate instants landed
     assert len(tr.instants("chaos_tick", "chaos")) > 0
-    assert len(tr.spans("serve_batch", "server")) == len(rep.batches)
+    spans = tr.spans("batch", "batches")
+    assert len(spans) == len(rep.batches)
+    for s, b in zip(sorted(spans, key=lambda s: s.attrs["bid"]),
+                    rep.batches):
+        assert s.attrs["bid"] == b.bid and s.attrs["rows"] == b.rows
+        assert s.attrs["pad_rows"] == b.pad_rows
+        assert s.t == b.t_dispatch and s.t_end == pytest.approx(b.t_done)
+    assert any(b.pad_rows for b in rep.batches)
+    assert all((b.rows + b.pad_rows) & (b.rows + b.pad_rows - 1) == 0
+               for b in rep.batches)
     assert len(tr.instants("migrate", "server")) == len(rep.migrations)
 
     # metrics agree with the report within the documented sketch error
@@ -451,3 +460,130 @@ def test_tracer_state_does_not_leak_across_runs():
     assert tr.open_spans() == []
     assert len(tr.events) > n1
     assert len(tr.spans("request")) == n_roots1 + len(rep2.records)
+
+
+# -- real-clock spans: the profiler's host plane ------------------------------
+
+ENGINE_PHASES = ("engine.control", "engine.inputs", "engine.device_wait",
+                 "engine.record")
+LOOP_PHASES = ("server.stack", "server.draw", "server.slot_forward",
+               "server.slot_mask", "server.merge", "server.package")
+
+
+def _profiled(log_dir, fn):
+    """``fn()`` under the JAX profiler into ``log_dir``: its result and the
+    program's host spans ``(name, start_ns, end_ns, stats)`` by start."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    with jax.profiler.trace(str(log_dir), profiler_options=opts):
+        out = fn()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(("engine.", "server."))]
+    return out, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def _lossy_server(fastpath):
+    from repro.core.simulator import FailureModel
+    return build_demo_server(_toy_ir(), feat=8, hidden=16, n_classes=3,
+                             seed=0, fastpath=fastpath,
+                             failure=FailureModel(crash_prob=0.5,
+                                                  outages=False))
+
+
+@pytest.fixture(scope="module")
+def loop_run(tmp_path_factory):
+    """The per-slot loop behind the engine on the real clock (measured
+    wall), once to compile, then again under the profiler with no
+    warm-up, so that every server call is one of the engine's batches."""
+    import dataclasses
+    srv = _lossy_server(fastpath=False)
+    rng = np.random.default_rng(4)
+    times = np.sort(rng.uniform(0.0, 0.03, 40))
+    sizes = rng.integers(1, 3, 40)
+    cfg = EngineConfig(max_batch=4, max_wait=0.002, input_dim=8, seed=0)
+    ServingEngine(srv, cfg).run(times, sizes)
+    eng = ServingEngine(srv, dataclasses.replace(cfg, warmup=False))
+    return _profiled(tmp_path_factory.mktemp("loop"),
+                     lambda: eng.run(times, sizes))
+
+
+def test_loop_phases_nest_in_engine_batch_with_their_stats(loop_run):
+    rep, spans = loop_run
+    batches = [s for s in spans if s[0] == "engine.batch"]
+    assert [b[3] for b in batches] == [
+        {"bid": b.bid, "n_requests": b.n_requests, "rows": b.rows,
+         "pad_rows": b.pad_rows, "plan_epoch": b.plan_epoch}
+        for b in rep.batches]
+    names = {s[0] for s in spans}
+    assert set(ENGINE_PHASES + LOOP_PHASES) <= names
+    assert "server.fused_step" not in names
+    for name, t0, t1, stats in spans:
+        if name == "engine.batch":
+            continue
+        outer = [b for b in batches if b[1] <= t0 and t1 <= b[2]]
+        assert len(outer) == 1, name
+        if name in ("server.slot_forward", "server.slot_mask"):
+            assert list(stats) == ["slot"] and 0 <= stats["slot"] < 2
+        else:
+            assert stats == {}
+    # each batch runs each arrived slot's forward once, in slot order
+    for b in batches:
+        slots = [s[3]["slot"] for s in spans
+                 if s[0] == "server.slot_forward" and b[1] <= s[1] < b[2]]
+        assert slots == sorted(set(slots))
+
+
+def test_loop_phases_cover_the_batch(loop_run):
+    """The phases leave little of a batch unnamed: the engine's own
+    phases and the server's top-level ones, which follow one another on
+    one thread, cover >= 90 % of the ``engine.batch`` spans."""
+    _, spans = loop_run
+    top = set(ENGINE_PHASES + LOOP_PHASES)
+    batch_ns = sum(s[2] - s[1] for s in spans if s[0] == "engine.batch")
+    phase_ns = sum(s[2] - s[1] for s in spans if s[0] in top)
+    assert batch_ns > 0 and 0.9 * batch_ns <= phase_ns <= batch_ns
+
+
+@pytest.mark.parametrize("fastpath", [False, True])
+def test_profiler_on_is_bit_identical_and_names_the_path(tmp_path,
+                                                         fastpath):
+    srv = _lossy_server(fastpath)
+    rng = np.random.default_rng(9)
+    xs = [rng.standard_normal((s, 8)).astype(np.float32)
+          for s in (1, 2, 1, 3, 2, 1, 1, 2)]
+
+    def serve():
+        out = srv.serve_batch(xs, rng=np.random.default_rng(5))
+        return ([r.logits for r in out], [r.arrived for r in out])
+    off = serve()
+    on, spans = _profiled(tmp_path, serve)
+    for a, b in zip(off[0] + off[1], on[0] + on[1]):
+        np.testing.assert_array_equal(a, b)
+    assert not all(a.all() for a in on[1])         # some requests masked
+    names = {s[0] for s in spans}
+    assert ("server.fused_step" in names) == fastpath
+    assert ("server.slot_forward" in names) != fastpath
+    assert {"server.stack", "server.draw", "server.package"} <= names
+
+
+def test_slot_programs_are_named_by_slot():
+    import re
+    srv = _lossy_server(fastpath=False)
+    x = np.zeros((2, 8), np.float32)
+    fns = srv.jitted_portions
+    assert len(fns) == 2
+    for k, fn in enumerate(fns):
+        text = fn.lower(x).compile().as_text()
+        assert re.match(rf"HloModule jit_padded_s{k}\b", text), text[:80]
