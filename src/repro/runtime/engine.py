@@ -415,8 +415,9 @@ class ServingEngine:
                 xs = [self._input(r.size) for r in reqs]
                 if pad_rows:
                     xs.append(self._input(pad_rows))  # filler, dropped
+                rng = self._batch_rng(bid)
             t0 = time.perf_counter()
-            results = self.server.serve_batch(xs, rng=self._batch_rng(bid))
+            results = self.server.serve_batch(xs, rng=rng)
             if self.cfg.service_model is None and results:
                 # serve_batch returns without waiting for the device (the
                 # logits sync is deferred to ServeResult access). In

@@ -240,6 +240,12 @@ class QuorumServer:
         default=None, init=False, repr=False)
     _fused_step_compute: Optional[Callable] = dataclasses.field(
         default=None, init=False, repr=False)
+    _mask_stack: Optional[Callable] = dataclasses.field(
+        default=None, init=False, repr=False)
+    # the per-slot loop's zeros portion of a slot no row received, by
+    # (B, Dk): put on the device once per shape, never per batch
+    _zero_portions: Dict = dataclasses.field(
+        default_factory=dict, init=False, repr=False)
     _coded_rt: Optional[Any] = dataclasses.field(
         default=None, init=False, repr=False)
     _compute_rt: Optional[Any] = dataclasses.field(
@@ -350,6 +356,32 @@ class QuorumServer:
                                         fc_scales, interpret=interpret)
 
         return jax.jit(step, static_argnames=("masked",))
+
+    def _mask_stack_step(self) -> Callable:
+        """The per-slot loop's one compiled program between the slot
+        forwards and the merge kernel, built once: stack the K (B, Dk)
+        portions and zero the rows of each slot that missed their
+        request's quorum. The (B, K) row mask arrives as the sampler's
+        numpy bools and is converted inside, so the loop issues no eager
+        op; a clean batch passes its all-ones mask too (multiplying by 1.0
+        is bit-exact), so each (B, K, Dk) compiles once."""
+        if self._mask_stack is None:
+            def mask_stack(portions, row_mask):
+                stacked = jnp.stack(portions)             # (K, B, Dk)
+                return stacked * row_mask.T[:, :, None].astype(
+                    stacked.dtype)
+
+            self._mask_stack = jax.jit(mask_stack)
+        return self._mask_stack
+
+    def _zero_portion(self, rows: int, width: int) -> jax.Array:
+        """The (rows, width) float32 zeros on the device, memoized (a put,
+        not an eager op)."""
+        zeros = self._zero_portions.get((rows, width))
+        if zeros is None:
+            zeros = jax.device_put(np.zeros((rows, width), np.float32))
+            self._zero_portions[(rows, width)] = zeros
+        return zeros
 
     def _invalidate_fused(self) -> None:
         self._fused_stacked = None
@@ -477,7 +509,8 @@ class QuorumServer:
         """Serve R stacked requests. On the fused fast path this is ONE
         jitted dispatch (stacked portion forwards + device-side masking +
         quorum merge in a single compiled program); the legacy flag path
-        issues one forward per partition + one quorum_aggregate launch.
+        issues one forward per arrived partition, one compiled
+        mask-and-stack program and one quorum_aggregate launch.
         Failures are drawn per request (one vectorized sample for the whole
         batch), and results are returned WITHOUT waiting for the device —
         the logits sync is deferred to :class:`ServeResult` access.
@@ -496,9 +529,10 @@ class QuorumServer:
 
         Each host phase runs under a ``server.*`` profiler span
         (``jax.profiler.TraceAnnotation``; a no-op while no trace is being
-        recorded): ``draw``, ``stack``, ``slot_forward`` / ``slot_mask``
-        (stat ``slot``), ``decode_ops``, ``merge`` or ``fused_step``, and
-        ``package``. None of them waits for the device."""
+        recorded): ``draw``, ``stack``, ``slot_forward`` (stat ``slot``),
+        ``decode_ops``, ``merge`` (on the replicate loop with the stat
+        ``masked_slots``) or ``fused_step``, and ``package``. None of them
+        waits for the device."""
         R = len(xs)
         if R == 0:
             return []
@@ -517,6 +551,7 @@ class QuorumServer:
             jitted = None
         else:
             jitted = self.jitted_portions      # fully-compiled private list
+            mask_stack = self._mask_stack_step()
             stacked = step = fc_q = None
         fc_weights, fc_bias = self.fc_weights, self.fc_bias
         arrays = self.arrays
@@ -527,9 +562,9 @@ class QuorumServer:
         # concurrent migration's new slot count against the old jitted list)
         Kp = len(jitted) if jitted is not None else len(fc_weights)
 
-        sizes = [int(x.shape[0]) for x in xs]
-        offs = np.concatenate([[0], np.cumsum(sizes)])
         with TraceAnnotation("server.stack"):
+            sizes = [int(x.shape[0]) for x in xs]
+            offs = np.concatenate([[0], np.cumsum(sizes)])
             # stack requests in numpy: an eager jnp.concatenate compiles one
             # XLA program per DISTINCT tuple of request shapes, which under
             # continuous batching (heterogeneous sizes) means a ~20ms
@@ -578,26 +613,28 @@ class QuorumServer:
                     _, arrived, latency = reduce_trials(arrays, alive, delay,
                                                         deadline)
 
-        # per-sample row mask: request r's rows of portion k are zeroed when
-        # k missed r's quorum (linear merge ⇒ exact per-request masking).
-        # The clean (all-arrived) batch skips building the (B, K) mask
-        clean = bool(arrived.all())
-        any_arrived = arrived.any(axis=0)                   # (K,)
-        # coded recovery engages only when a CODED slot's systematic share
-        # is erased — while those all arrive the coded flow IS the plain
-        # flow (identity decode), so it is skipped entirely: failure-free
-        # coded serving — and any outage confined to replicate slots or
-        # parity shares — is bit-identical to (and as fast as) uncoded
-        decode_needed = (rt is not None and share_arrived is not None
-                         and not bool(
-                             share_arrived[:, rt.coded_slots].all()))
-        # compute-coded slots decode from the k EARLIEST shard arrivals
-        # (cancel-on-first-k). While those happen to be the systematic
-        # shards — the all-alive steady state, by the planner's placement —
-        # the decode is the identity and the plain path is bit-exact, so it
-        # is skipped exactly like the output-coded fast case above
-        compute_decode = (rtc is not None and share_t is not None
-                          and rtc.needs_decode(share_t))
+            # per-sample row mask: request r's rows of portion k are zeroed
+            # when k missed r's quorum (linear merge ⇒ exact per-request
+            # masking). The clean (all-arrived) batch skips building it
+            clean = bool(arrived.all())
+            any_arrived = arrived.any(axis=0)               # (K,)
+            # coded recovery engages only when a CODED slot's systematic
+            # share is erased — while those all arrive the coded flow IS the
+            # plain flow (identity decode), so it is skipped entirely:
+            # failure-free coded serving — and any outage confined to
+            # replicate slots or parity shares — is bit-identical to (and as
+            # fast as) uncoded
+            decode_needed = (rt is not None and share_arrived is not None
+                             and not bool(
+                                 share_arrived[:, rt.coded_slots].all()))
+            # compute-coded slots decode from the k EARLIEST shard arrivals
+            # (cancel-on-first-k). While those happen to be the systematic
+            # shards — the all-alive steady state, by the planner's
+            # placement — the decode is the identity and the plain path is
+            # bit-exact, so it is skipped exactly like the output-coded fast
+            # case above
+            compute_decode = (rtc is not None and share_t is not None
+                              and rtc.needs_decode(share_t))
         if fastpath:
             if fc_q is not None:
                 fc_w, fc_scales = fc_q.q, fc_q.scale
@@ -670,34 +707,36 @@ class QuorumServer:
                         stacked_p, fc_weights, fc_bias,
                         jnp.asarray(any_arrived, jnp.int32))
         else:
-            row_arrived = None if clean else np.repeat(arrived, sizes, axis=0)
             if fastpath:
                 # numpy operands cross the jit boundary directly (fast-path
                 # device_put) — no eager conversions before the single
                 # dispatch
                 with TraceAnnotation("server.fused_step"):
+                    row_arrived = (None if clean
+                                   else np.repeat(arrived, sizes, axis=0))
                     logits = step(stacked, x_all, row_arrived, any_arrived,
                                   fc_w, fc_scales, fc_bias,
                                   masked=not clean)
             else:
-                Dk = fc_weights.shape[1]
-                portions = []
-                for kslot in range(Kp):
-                    if not any_arrived[kslot]:
-                        with TraceAnnotation("server.slot_mask", slot=kslot):
-                            portions.append(jnp.zeros((B, Dk), jnp.float32))
-                        continue
-                    p = forward(kslot)
-                    if not clean and not row_arrived[:, kslot].all():
-                        with TraceAnnotation("server.slot_mask", slot=kslot):
-                            p = p * jnp.asarray(row_arrived[:, kslot, None],
-                                                p.dtype)
-                    portions.append(p)
-                with TraceAnnotation("server.merge"):
-                    stacked_p = jnp.stack(portions)        # (K, B, Dk)
+                # no eager op on this loop: a slot no row received is a
+                # memoized zeros placeholder (its mask column is zero
+                # anyway), and the masking and stacking run as ONE compiled
+                # program with the numpy mask crossing the jit boundary
+                Dk = int(fc_weights.shape[1])
+                portions = tuple(
+                    forward(kslot) if any_arrived[kslot]
+                    else self._zero_portion(B, Dk)
+                    for kslot in range(Kp))
+                with TraceAnnotation("server.merge") as span:
+                    # slots that some of the batch's requests received and
+                    # others did not: where the program's masking engages
+                    span.set_metadata(masked_slots=int(
+                        (any_arrived & ~arrived.all(axis=0)).sum()))
+                    stacked_p = mask_stack(
+                        portions, np.repeat(arrived, sizes, axis=0))
                     logits = K.quorum_aggregate(
                         stacked_p, fc_weights, fc_bias,
-                        jnp.asarray(any_arrived, jnp.int32))
+                        any_arrived.astype(np.int32))
         with TraceAnnotation("server.package"):
             return self._package(xs, R, sizes, offs, logits, arrived,
                                  latency, alive, arrays,

@@ -96,6 +96,76 @@ def test_fused_bit_identical_under_stochastic_outages():
         assert (a.arrived == b.arrived).all()
 
 
+# -- the per-slot loop: one compiled mask-and-stack program -------------------
+
+_LOOP_FAILURES = {
+    "clean": FailureModel(outages=False),
+    "stochastic": FailureModel(crash_prob=0.5, outages=False),
+    "slot_unreached": FailureModel(forced_failures=["a", "b"], outages=False),
+}
+
+
+def _eager_loop_logits(srv, xs, arrived):
+    """The loop as a sequence of eager ops: each arrived slot's forward,
+    its missed rows zeroed by an eager multiply, zeros for a slot no row
+    received, ``jnp.stack`` and the ``quorum_aggregate`` launch."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    sizes = [len(x) for x in xs]
+    x = jnp.asarray(np.concatenate(xs))
+    row_arrived = np.repeat(arrived, sizes, axis=0)
+    any_arrived = arrived.any(axis=0)
+    Dk = srv.fc_weights.shape[1]
+    portions = []
+    for k, fn in enumerate(srv.jitted_portions):
+        if not any_arrived[k]:
+            portions.append(jnp.zeros((len(x), Dk), jnp.float32))
+            continue
+        p = fn(x)
+        if not row_arrived[:, k].all():
+            p = p * jnp.asarray(row_arrived[:, k, None], p.dtype)
+        portions.append(p)
+    return np.asarray(ops.quorum_aggregate(
+        jnp.stack(portions), srv.fc_weights, srv.fc_bias,
+        jnp.asarray(any_arrived, jnp.int32)))
+
+
+@pytest.mark.parametrize("sizes", [(1, 2, 1), (3, 1, 2, 4, 1, 2, 2, 1)])
+@pytest.mark.parametrize("case", sorted(_LOOP_FAILURES))
+def test_loop_bit_identical_to_eager_sequence(case, sizes):
+    _, legacy = _pair()
+    legacy.failure = _LOOP_FAILURES[case]
+    xs = [_x(n, seed=20 + i) for i, n in enumerate(sizes)]
+    res = legacy.serve_batch(xs, rng=np.random.default_rng(6))
+    arrived = np.stack([r.arrived for r in res])
+    partly = arrived.any(axis=0) & ~arrived.all(axis=0)
+    assert {"clean": arrived.all(),
+            "stochastic": partly.any(),
+            "slot_unreached": not arrived[:, 0].any()}[case]
+    got = np.concatenate([r.logits for r in res])
+    np.testing.assert_array_equal(got,
+                                  _eager_loop_logits(legacy, xs, arrived))
+
+
+def test_loop_masks_clean_and_masked_batches_with_one_program():
+    _, legacy = _pair()
+    xs = [_x(2), _x(2, seed=9)]                    # 4 rows
+    legacy.failure = FailureModel(outages=False)
+    assert legacy.serve_batch(xs)[0].arrived.all()
+    legacy.failure = FailureModel(forced_failures=["c", "d"], outages=False)
+    assert not legacy.serve_batch(xs)[0].arrived.all()
+    legacy.failure = FailureModel(crash_prob=0.5, outages=False)
+    for seed in range(3):
+        legacy.serve_batch(xs, rng=np.random.default_rng(seed))
+    assert legacy._mask_stack._cache_size() == 1   # one per (B, K, Dk)
+    legacy.failure = FailureModel(outages=False)
+    legacy.serve_batch(xs + [_x(4)])               # 8 rows: a new shape
+    assert legacy._mask_stack._cache_size() == 2
+    # the unreached slot's zeros were made once for the 4-row shape
+    assert list(legacy._zero_portions) == [(4, legacy.fc_weights.shape[1])]
+
+
 # -- bit-identity across live migrations --------------------------------------
 
 def test_fused_bit_identity_survives_remove_repair_migrate():

@@ -467,7 +467,7 @@ def test_tracer_state_does_not_leak_across_runs():
 ENGINE_PHASES = ("engine.control", "engine.inputs", "engine.device_wait",
                  "engine.record")
 LOOP_PHASES = ("server.stack", "server.draw", "server.slot_forward",
-               "server.slot_mask", "server.merge", "server.package")
+               "server.merge", "server.package")
 
 
 def _profiled(log_dir, fn):
@@ -528,16 +528,23 @@ def test_loop_phases_nest_in_engine_batch_with_their_stats(loop_run):
         for b in rep.batches]
     names = {s[0] for s in spans}
     assert set(ENGINE_PHASES + LOOP_PHASES) <= names
-    assert "server.fused_step" not in names
+    assert not {"server.fused_step", "server.slot_mask"} & names
+    masked = []
     for name, t0, t1, stats in spans:
         if name == "engine.batch":
             continue
         outer = [b for b in batches if b[1] <= t0 and t1 <= b[2]]
         assert len(outer) == 1, name
-        if name in ("server.slot_forward", "server.slot_mask"):
+        if name == "server.slot_forward":
             assert list(stats) == ["slot"] and 0 <= stats["slot"] < 2
+        elif name == "server.merge":
+            assert list(stats) == ["masked_slots"]
+            masked.append(stats["masked_slots"])
         else:
             assert stats == {}
+    # one merge a batch; the lossy server masks part of some batches
+    assert len(masked) == len(batches)
+    assert all(0 <= n <= 2 for n in masked) and max(masked) > 0
     # each batch runs each arrived slot's forward once, in slot order
     for b in batches:
         slots = [s[3]["slot"] for s in spans
