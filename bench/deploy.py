@@ -1,22 +1,23 @@
 """A configuration file made into the program's objects.
 
 ``load_config`` reads ``bench/configs/<name>.json`` (and its activation
-graph); ``build`` makes the weights from the seed on the device in one
-jitted call, and hands them to the program's normal path:
-``PlanIR`` -> ``Ensemble`` -> ``server_from_ensemble``. The same weight
-arrays stay with the benchmark for the reference.
+graph); ``build`` hands it to its kind (``bench/kinds/<kind>.py``), which
+makes the weights from the seed on the device in one jitted call and
+serves them through the program's normal path: ``PlanIR`` -> ``Ensemble``
+-> ``server_from_ensemble`` (``serve_ensemble``). The same weight arrays
+stay with the benchmark for the reference.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import pathlib
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Sequence
 
 import jax
 import numpy as np
 
-from bench import reference as R
+from bench import kinds
 
 BENCH = pathlib.Path(__file__).resolve().parent
 
@@ -41,6 +42,12 @@ def load_config(name_or_path) -> Dict:
     return cfg
 
 
+def kind_of(cfg: Dict):
+    """The module of the configuration's kind: its ``"kind"``, else
+    ``cnn``."""
+    return kinds.load(cfg.get("kind", "cnn"))
+
+
 def slot_shapes(cfg: Dict) -> tuple:
     """((arch, width), ...) of the plan's slots, slot order."""
     return tuple((s["arch"], int(s["width"])) for s in cfg["plan"]["slots"])
@@ -51,8 +58,9 @@ class Deployment:
     """The program's server over one configuration, and the benchmark's own
     copy of what it serves."""
     cfg: Dict
+    kind: Any                    # the module bench/kinds/<kind>.py
     slots: tuple                 # ((arch, width), ...)
-    weights: Dict                # reference layout, device arrays
+    weights: Any                 # the kind's reference layout, device arrays
     server: Any                  # repro.runtime.serving.QuorumServer
     ir: Any                      # repro.core.plan_ir.PlanIR as deployed
 
@@ -82,20 +90,9 @@ def plan_ir(cfg: Dict):
                   plan["d_th"], plan["p_th"]).validate()
 
 
-def _program_student(arch: str, width: int, n_classes: int):
-    """(config, forward) of the program's student ``arch`` at ``width``."""
-    from repro.models import cnn
-    if arch.startswith("wrn"):
-        _, d, w = arch.split("-")
-        return (cnn.WRNConfig(arch, int(d), int(w), n_classes,
-                              final_channels=width), cnn.wrn_forward)
-    if arch == "mobilenetv2":
-        return cnn.MBV2Config(arch, n_classes, final_channels=width), \
-            cnn.mbv2_forward
-    raise KeyError(arch)
-
-
-def _same_layout(ours, theirs, what: str) -> None:
+def same_layout(ours, theirs, what: str) -> None:
+    """Refuse weights whose pytree of shapes and dtypes is not the
+    program's."""
     a = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), ours)
     b = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), theirs)
     if a != b:
@@ -103,37 +100,24 @@ def _same_layout(ours, theirs, what: str) -> None:
                          f"from the configuration's architecture")
 
 
-def make_weights(cfg: Dict, seed: int) -> Dict:
-    """Every slot's student and the FC head, from ``seed``, on the device,
-    in one jitted call (float32, as served)."""
-    slots = slot_shapes(cfg)
-    fn = jax.jit(lambda k: R.init_ensemble(k, cfg["archs"], slots,
-                                           cfg["n_classes"]))
-    return jax.block_until_ready(fn(key_for(seed)))
-
-
-def build(cfg: Dict, seed: int, *, ir=None) -> Deployment:
-    """Weights from ``seed`` served by ``server_from_ensemble``."""
+def serve_ensemble(cfg: Dict, kind, weights, students: Sequence, fc: Dict,
+                   seed: int) -> Deployment:
+    """``students`` ((program config, params, forward) per slot) and the
+    merge head ``fc`` served by ``server_from_ensemble`` over the plan."""
     from repro.core.pipeline import Ensemble
-    from repro.models import cnn
     from repro.runtime.serving import server_from_ensemble
     slots = slot_shapes(cfg)
-    ir = plan_ir(cfg) if ir is None else ir
-    weights = make_weights(cfg, seed)
-    n_classes = cfg["n_classes"]
-    students: List = []
-    for k, (arch, width) in enumerate(slots):
-        pcfg, fwd = _program_student(arch, width, n_classes)
-        shapes = jax.eval_shape(
-            lambda: cnn.make_student(jax.random.key(0), arch, n_classes,
-                                     width)[1])
-        _same_layout(weights["students"][k], shapes, f"slot {k} ({arch})")
-        students.append((pcfg, weights["students"][k], fwd))
-    ens = Ensemble(plan=ir.to_plan(), students=students, fc=weights["fc"],
+    ir = plan_ir(cfg)
+    ens = Ensemble(plan=ir.to_plan(), students=list(students), fc=fc,
                    part_dims=[w for _, w in slots], teacher_acc=float("nan"),
                    ir=ir)
     server = server_from_ensemble(ens, seed=int(seed) % (1 << 32))
-    return Deployment(cfg, slots, weights, server, ir)
+    return Deployment(cfg, kind, slots, weights, server, ir)
+
+
+def build(cfg: Dict, seed: int) -> Deployment:
+    """The configuration's kind builds its deployment from ``seed``."""
+    return kind_of(cfg).build(cfg, seed)
 
 
 def group_members(cfg: Dict, group: int) -> List[str]:
@@ -147,6 +131,6 @@ def group_members(cfg: Dict, group: int) -> List[str]:
 def describe(dep: Deployment) -> str:
     srv = dep.server
     path = "fused megastep" if srv.fastpath_active else "per-slot loop"
-    return (f"K={len(dep.slots)} path={path} slots="
+    return (f"{pathlib.Path(dep.kind.__file__).stem} K={len(dep.slots)} path={path} slots="
             + ", ".join(f"{a}/{w}x{len(s['members'])}" for (a, w), s in
                         zip(dep.slots, dep.cfg["plan"]["slots"])))

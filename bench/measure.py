@@ -3,6 +3,8 @@
 Each metric of ``BENCHMARK.json`` has a reader ``bench/metrics/<name>.py``
 with ``read(run) -> float | None``; it returns None when the run holds
 nothing for it to read (a share of a roofline is then left out, never 0).
+Operations and bytes come from the configuration's kind (``slot_cost``,
+``merge_cost``), counted from shapes alone.
 """
 from __future__ import annotations
 
@@ -10,14 +12,11 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
-from bench import reference as R
 from bench import trace_reduce
-from bench.counts import quorum_aggregate as QA
-from bench.counts import student_forward as SF
 from bench.window import Window
 
 BENCH = pathlib.Path(__file__).resolve().parent
@@ -47,6 +46,7 @@ def peaks(device_kind: str) -> Dict:
 
 @dataclasses.dataclass
 class Run:
+    kind: Any                  # the configuration's bench/kinds/<kind>.py
     cfg: Dict
     slots: tuple
     window: Window
@@ -62,12 +62,6 @@ class Run:
     def peaks(self) -> Dict:
         return peaks(self.device_kind)
 
-    def spec(self, arch: str) -> Dict:
-        return R.arch_spec(self.cfg["archs"], arch)
-
-    def image_shape(self):
-        return tuple(self.cfg["image_shape"])
-
     def in_window(self, t) -> np.ndarray:
         return np.asarray(t) <= self.seconds
 
@@ -79,25 +73,28 @@ class Run:
         return [b for b in self.window.batches
                 if span[0] <= b.t_dispatch < span[1]]
 
+    def least_s(self, flops: float, nbytes: float) -> float:
+        """The least time the chip takes: the larger of operations over
+        peak FLOP/s and bytes over peak bytes/s."""
+        pk = self.peaks
+        return max(flops / pk["bf16_flops_per_s"],
+                   nbytes / pk["hbm_bytes_per_s"])
+
     def student_counts(self, batches) -> tuple:
         """(flops, least seconds) of the student forwards ``batches`` ran,
         padded rows included (the device computes them)."""
-        pk = self.peaks
         fl = least = 0.0
         for b in batches:
             if b.computed is None:
                 continue
             rows = b.rows + b.padded_rows
             for k in np.flatnonzero(b.computed):
-                arch = self.slots[k][0] if k < len(self.slots) else None
-                if arch is None or k >= len(b.slot_widths):
+                if k >= len(self.slots) or k >= len(b.slot_widths):
                     continue
-                spec, w = self.spec(arch), b.slot_widths[k]
-                f = SF.flops(spec, w, self.image_shape(), rows)
-                by = SF.bytes_moved(spec, w, self.image_shape(), rows)
+                f, by = self.kind.slot_cost(self.cfg, self.slots[k][0],
+                                            b.slot_widths[k], rows)
                 fl += f
-                least += max(f / pk["bf16_flops_per_s"],
-                             by / pk["hbm_bytes_per_s"])
+                least += self.least_s(f, by)
         return fl, least
 
     def merge_counts(self, batches) -> tuple:
@@ -106,27 +103,25 @@ class Run:
         pk = self.peaks
         fl = least = 0.0
         by_c = by_m = 0.0
-        C = self.cfg["n_classes"]
         for b in batches:
             if b.computed is None or not b.slot_widths:
                 continue
-            n, rows, dk = int(b.computed.sum()), b.rows + b.padded_rows, \
-                max(b.slot_widths)
-            f, by = QA.flops(n, rows, dk, C), QA.bytes_moved(n, rows, dk, C)
+            f, by = self.kind.merge_cost(self.cfg, int(b.computed.sum()),
+                                         b.rows + b.padded_rows,
+                                         max(b.slot_widths))
             fl += f
             by_c += f / pk["bf16_flops_per_s"]
             by_m += by / pk["hbm_bytes_per_s"]
-            least += max(f / pk["bf16_flops_per_s"],
-                         by / pk["hbm_bytes_per_s"])
+            least += self.least_s(f, by)
         return fl, least, ("memory" if by_m > by_c else "compute")
 
-    def model_flops_per_image(self) -> float:
-        """One image through every slot's student and the merge."""
-        C = self.cfg["n_classes"]
-        f = sum(SF.flops(self.spec(a), w, self.image_shape(), 1)
+    def model_flops_per_row(self) -> float:
+        """One row of a request through every slot's student and the
+        merge."""
+        f = sum(self.kind.slot_cost(self.cfg, a, w, 1)[0]
                 for a, w in self.slots)
-        return f + QA.flops(len(self.slots), 1, max(w for _, w in self.slots),
-                            C)
+        return f + self.kind.merge_cost(self.cfg, len(self.slots), 1,
+                                        max(w for _, w in self.slots))[0]
 
 
 def reader(name: str):

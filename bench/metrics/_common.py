@@ -29,3 +29,4 @@ def idle_share(run):
 STUDENT_MODULES = r"^jit_padded"
 # the quorum merge's Pallas kernel
 MERGE_OPS = r"agg_kernel|quorum_aggregate"
+

@@ -2,15 +2,18 @@
 
 The engine and the server annotate their host phases with
 ``jax.profiler.TraceAnnotation`` (``engine.*`` and ``server.*``, listed in
-``PROGRAM_SPANS``); each micro-batch is one ``engine.batch`` span with the
-others nested in it. They land on the host plane of the same ``.xplane.pb``
-as the device ops, on the same clock. This module reads them beside what
-``trace_reduce`` reads:
+``trace_reduce.PROGRAM_SPANS``); each micro-batch is one ``engine.batch``
+span with the others nested in it. They land on the host plane of the same
+``.xplane.pb`` as the device ops, on the same clock, and
+``trace_reduce.load`` keeps them as ``Trace.program``. This module reads
+them:
 
-- ``per_batch_ms``: the time of some phases per micro-batch;
-- ``idle_by_phase``: the chip's idle time by the innermost program span
-  over each gap, else by the benchmark's span as ``Trace.idle_gaps`` puts
-  it, else the engine's loop.
+- ``per_batch_ms``: the median time of some phases in a micro-batch;
+- ``device_per_batch_ms``: the median time the chip was busy in one;
+- ``phase_split``: the mean time of every phase in a micro-batch;
+- ``Trace.idle_by_phase``: the chip's idle time by the innermost program
+  span over each gap, else by the benchmark's span as ``Trace.idle_gaps``
+  puts it, else the engine's loop.
 
 A trace of a program without these spans gives no spans, and every reading
 is then None or empty. Run alone, it prints the split of one trace:
@@ -22,8 +25,7 @@ from __future__ import annotations
 import json
 import os
 import sys
-from collections import defaultdict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -32,126 +34,81 @@ if __package__ in (None, ""):
         os.path.abspath(__file__))))
 
 from bench import trace_reduce  # noqa: E402
+from bench.trace_reduce import PROGRAM_SPANS  # noqa: E402
 
-PROGRAM_SPANS = (
-    "engine.batch", "engine.control", "engine.inputs", "engine.device_wait",
-    "engine.record", "server.draw", "server.stack", "server.slot_forward",
-    "server.slot_mask", "server.merge", "server.decode_ops",
-    "server.fused_step", "server.package")
-# the per-slot loop: its forwards, eager masks and the merge launch
-SLOT_LOOP = ("server.slot_forward", "server.slot_mask", "server.merge")
-
-Span = Tuple[str, float, float]          # (name, start s, duration s)
+# the per-slot loop: its forwards and the merge launch
+SLOT_LOOP = ("server.slot_forward", "server.merge")
 
 
-def load_spans(path: str) -> List[Span]:
-    """The program spans of the trace's host planes, by start time."""
-    from jax.profiler import ProfileData
-    out: List[Span] = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name in PROGRAM_SPANS:
-                    out.append((e.name, e.start_ns * 1e-9,
-                                e.duration_ns * 1e-9))
-    return sorted(out, key=lambda s: (s[1], -s[2]))
-
-
-def load(path: str) -> Tuple[trace_reduce.Trace, List[Span]]:
-    """``trace_reduce.load(path)`` and the program spans. The window is the
-    benchmark spans' extent, else the program spans' (a trace of a live
-    engine holds no benchmark spans)."""
-    spans = load_spans(path)
-    try:
-        return trace_reduce.load(path), spans
-    except ValueError:
-        if not spans:
-            raise
-        window = (spans[0][1], max(t + d for _, t, d in spans))
-        return trace_reduce.load(path, window), spans
-
-
-def batches_in(trace: trace_reduce.Trace, spans: Sequence[Span]) -> int:
+def batches(trace: trace_reduce.Trace) -> List[trace_reduce.Span]:
     """``engine.batch`` spans that start inside the traced window."""
     lo, hi = trace.window
-    return sum(1 for n, t, _ in spans
-               if n == "engine.batch" and lo <= t < hi)
+    return [s for s in trace.program
+            if s.name == "engine.batch" and lo <= s.t < hi]
 
 
-def per_batch_ms(trace: trace_reduce.Trace, spans: Sequence[Span],
+def per_batch_s(trace: trace_reduce.Trace,
+                names: Sequence[str]) -> np.ndarray:
+    """Seconds of the ``names`` spans that start inside each ``engine.batch``
+    of ``batches``, clipped to the window, one entry a batch."""
+    hi = trace.window[1]
+    mine = [s for s in trace.program if s.name in names]
+    starts = np.asarray([s.t for s in mine])
+    ends = np.cumsum([0.0] + [max(0.0, min(s.t + s.d, hi) - s.t)
+                              for s in mine])
+    out = []
+    for b in batches(trace):
+        i, j = np.searchsorted(starts, [b.t, b.t + b.d], side="left")
+        out.append(ends[j] - ends[i])
+    return np.asarray(out, np.float64)
+
+
+def per_batch_ms(trace: trace_reduce.Trace,
                  names: Sequence[str]) -> Optional[float]:
-    """Summed durations of the ``names`` spans, clipped to the window, per
-    ``engine.batch`` that starts in it, ms; None without program spans."""
-    n = batches_in(trace, spans)
-    if not n:
+    """Median over the traced batches of the time of their ``names`` spans,
+    ms; None without program spans."""
+    secs = per_batch_s(trace, names)
+    return 1e3 * float(np.median(secs)) if secs.size else None
+
+
+def device_per_batch_ms(trace: trace_reduce.Trace) -> Optional[float]:
+    """Median over the traced batches of the time an op ran on the chip
+    inside each ``engine.batch`` span (the union of the ops, averaged over
+    the chips), ms; None without program spans or device ops."""
+    bs = batches(trace)
+    if not bs or not trace.ops:
         return None
-    lo, hi = trace.window
-    secs = sum(max(0.0, min(t + d, hi) - max(t, lo))
-               for name, t, d in spans if name in names)
-    return 1e3 * secs / n
-
-
-def phase_split(trace: trace_reduce.Trace,
-                spans: Sequence[Span]) -> Dict[str, float]:
-    """Milliseconds per batch of every program span that occurs."""
-    names = sorted({s[0] for s in spans}, key=PROGRAM_SPANS.index)
-    return {n: per_batch_ms(trace, spans, (n,)) for n in names}
-
-
-def _benchmark_span(host: Sequence[Span], starts: np.ndarray,
-                    mid: float) -> Optional[str]:
-    """The benchmark span ``Trace.idle_gaps`` puts the instant ``mid``
-    under: the innermost of ``trace_reduce.HOST_SPANS`` over it."""
-    order = {name: i for i, name in enumerate(trace_reduce.HOST_SPANS)}
-    i = int(np.searchsorted(starts, mid, side="right"))
-    best = None
-    for name, t, d in host[max(0, i - 64):i]:
-        if t <= mid < t + d and (best is None or order[name] < order[best]):
-            best = name
-    return best
-
-
-def idle_by_phase(trace: trace_reduce.Trace, spans: Sequence[Span],
-                  n: int = 20) -> List[List]:
-    """Idle seconds on the chips by the innermost program span over each
-    gap's midpoint (the one that started last; program spans nest), else
-    the benchmark span as ``Trace.idle_gaps`` chooses it, else
-    ``engine_loop``. Sums to the window less the busy time, as
-    ``idle_gaps`` does."""
-    host = sorted(trace.host, key=lambda s: s[1])
-    h_starts = np.asarray([s[1] for s in host])
-    p_starts = np.asarray([s[1] for s in spans])
-    p_ends = np.asarray([s[1] + s[2] for s in spans])
-    tot: Dict[str, float] = defaultdict(float)
+    per = np.zeros(len(bs))
     for c in sorted(trace.ops):
-        b = trace.busy(c)
-        edges = [trace.window[0]] + list(b.ravel()) + [trace.window[1]]
-        for lo, hi in zip(edges[0::2], edges[1::2]):
-            if hi <= lo:
-                continue
-            mid = 0.5 * (lo + hi)
-            i = int(np.searchsorted(p_starts, mid, side="right"))
-            j = max(0, i - 256)
-            over = np.flatnonzero(p_ends[j:i] > mid)
-            name = (spans[j + over[-1]][0] if over.size
-                    else _benchmark_span(host, h_starts, mid)
-                    or "engine_loop")
-            tot[name] += (hi - lo) / len(trace.ops)
-    return [[k, v] for k, v in sorted(tot.items(),
-                                      key=lambda kv: -kv[1])[:n]]
+        busy = trace.busy(c)
+        for k, b in enumerate(bs):
+            lo, hi = b.t, b.t + b.d
+            i = int(np.searchsorted(busy[:, 1], lo, side="right"))
+            j = int(np.searchsorted(busy[:, 0], hi, side="left"))
+            iv = busy[i:j]
+            per[k] += float((np.minimum(iv[:, 1], hi)
+                             - np.maximum(iv[:, 0], lo)).sum())
+    return 1e3 * float(np.median(per / len(trace.ops)))
+
+
+def phase_split(trace: trace_reduce.Trace) -> Dict[str, float]:
+    """Mean milliseconds per batch of every program span that occurs, so
+    that the phases add up to ``engine.batch``."""
+    names = sorted({s.name for s in trace.program}, key=PROGRAM_SPANS.index)
+    return {n: 1e3 * float(np.mean(per_batch_s(trace, (n,))))
+            for n in names}
 
 
 def report(path: str) -> Dict:
     """Everything this module reads from one trace, for a log line."""
-    trace, spans = load(path)
+    trace = trace_reduce.load(path)
     return {"window_s": trace.window_s, "busy_s": trace.busy_s(),
-            "batches": batches_in(trace, spans),
-            "slot_loop_ms": per_batch_ms(trace, spans, SLOT_LOOP),
-            "ms_per_batch": phase_split(trace, spans),
+            "batches": len(batches(trace)),
+            "slot_loop_ms": per_batch_ms(trace, SLOT_LOOP),
+            "device_ms": device_per_batch_ms(trace),
+            "ms_per_batch": phase_split(trace),
             "idle_gaps": trace.idle_gaps(),
-            "idle_gaps_by_phase": idle_by_phase(trace, spans)}
+            "idle_gaps_by_phase": trace.idle_by_phase()}
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:

@@ -3,10 +3,12 @@
     python3 bench/run.py --workload c10-poisson --seed 7 --seconds 10 --trace 0
 
 The cell names a configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<traffic>.json``). The run makes the weights
-and the traffic from ``--seed``, serves the mix through the program's
-engine for ``--seconds`` on the real clock, and checks the window's answers
-against the plain reference. With ``--trace 0`` it reports the cell's
+traffic mix (``bench/traffic/<traffic>.json``). The configuration's kind
+(``bench/kinds/<kind>.py``) makes the weights and the requests' inputs
+from ``--seed`` and holds the plain reference; the run draws the traffic
+from ``--seed``, serves the mix through the program's engine for
+``--seconds`` on the real clock, and checks the window's answers against
+the reference. With ``--trace 0`` it reports the cell's
 end-to-end metrics; with ``--trace 1`` its per-layer metrics, read by
 ``bench/metrics/<name>.py`` from the run's records and a profiler trace of
 a few seconds of the window. The last line of standard output is the
@@ -37,10 +39,8 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-# Limits of the numbers compared for ``correct`` (PERF.md gives the
-# readings each was set from).
-REL_GAP_P90 = 4e-3
-MAX_REL_ERR = 5e-2
+# Coverage of the comparison; the limits of the numbers compared are the
+# configuration's kind's (``LIMITS`` of ``bench/kinds/<kind>.py``).
 MIN_COMPARED = 50
 
 
@@ -123,19 +123,18 @@ def describe_window(w, seconds: float) -> None:
             for r in w.repairs))
 
 
-def compare_window(dep, win, pool, **how):
+def compare_window(dep, win, **how):
     from bench import check
-    base = np.asarray(dep.ir.partition)
-    return check.compare(dep.cfg, dep.slots, dep.weights, base, win.kept,
-                         win.offsets, win.sizes, pool, **how)
+    return check.compare(dep, win.kept, win.inputs, win.sizes, **how)
 
 
-def verdict(cmp, mix: Dict) -> Dict:
-    """The numbers compared, each beside its limit."""
-    out = {"rel_gap_p90": {"value": cmp.rel_gap_p90, "limit": REL_GAP_P90,
-                           "rule": "<="},
-           "max_rel_err": {"value": cmp.max_rel_err, "limit": MAX_REL_ERR,
-                           "rule": "<="},
+def verdict(cmp, mix: Dict, limits: Dict) -> Dict:
+    """The numbers compared, each beside its limit (``limits``, the
+    kind's, for the two gaps)."""
+    out = {"rel_gap_p90": {"value": cmp.rel_gap_p90,
+                           "limit": limits["rel_gap_p90"], "rule": "<="},
+           "max_rel_err": {"value": cmp.max_rel_err,
+                           "limit": limits["max_rel_err"], "rule": "<="},
            "requests_compared": {"value": cmp.requests,
                                  "limit": MIN_COMPARED, "rule": ">="},
            "degraded_compared": {"value": cmp.degraded, "limit": 1,
@@ -203,8 +202,8 @@ def execute(args, bench: Dict, cell: Dict, cfg: Dict, mix: Dict,
     try:
         win = window.serve_window(dep, mix, args.seed, args.seconds,
                                   counter=counter, trace_dir=tdir)
-        run = measure.Run(cfg, dep.slots, win, win.setup_end - T_START,
-                          dev["kind"])
+        run = measure.Run(dep.kind, cfg, dep.slots, win,
+                          win.setup_end - T_START, dev["kind"])
         describe_window(win, args.seconds)
         mem = jax.devices()[0].memory_stats() or {}
         dev["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
@@ -228,18 +227,20 @@ def execute(args, bench: Dict, cell: Dict, cfg: Dict, mix: Dict,
                      "idle_gaps": run.trace.idle_gaps()}
         log(f"trace: busy {dev['busy_s']!r} s of {dev['window_s']!r} s; "
             f"{json.dumps(breakdown)}")
-    pool = window.image_pool(cfg, args.seed)
+        log("trace: idle_gaps_by_phase "
+            + json.dumps(run.trace.idle_by_phase()))
     # the reference runs once the program's state is freed
     dep.server = None
     gc.collect()
     t_check = time.perf_counter()
-    cmp = compare_window(dep, win, pool)
-    v = verdict(cmp, mix)
+    limits = dep.kind.LIMITS
+    cmp = compare_window(dep, win)
+    v = verdict(cmp, mix, limits)
     others = {}
     if args.control:
         for who in ("control", "fault"):
-            c = compare_window(dep, win, pool, **{who: True})
-            others[who] = {"correct": passes(verdict(c, mix)),
+            c = compare_window(dep, win, **{who: True})
+            others[who] = {"correct": passes(verdict(c, mix, limits)),
                            "rel_gap_p90": c.rel_gap_p90,
                            "max_rel_err": c.max_rel_err}
     for who, c in [("program", {"correct": passes(v),

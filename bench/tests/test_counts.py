@@ -2,9 +2,11 @@
 import jax
 import numpy as np
 
-from bench import reference as R
+from bench import kinds
 from bench.counts import quorum_aggregate as QA
 from bench.counts import student_forward as SF
+
+CNN = kinds.load("cnn")
 
 WRN = {"kind": "wrn", "depth": 10, "widen": 1}
 MBV2 = {"kind": "mbv2", "stem": 8, "blocks": [[1, 8, 1, 1], [2, 16, 1, 2]]}
@@ -41,7 +43,7 @@ def _conv_weights(p):
 
 def test_bytes_count_the_reference_weights():
     for spec, width, shape in [(WRN, 16, (8, 8, 3)), (MBV2, 12, (4, 4, 3))]:
-        p = R.init_student(jax.random.key(0), spec, width, 10)
+        p = CNN.init_student(jax.random.key(0), spec, width, 10)
         h, w, c = shape
         assert SF.bytes_moved(spec, width, shape, 3) == 4 * (
             _conv_weights(p) + 3 * h * w * c + 3 * width)

@@ -20,7 +20,7 @@ import types
 import numpy as np
 import pytest
 
-from bench import deploy
+from bench import deploy, kinds
 from bench import traffic as T
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -37,6 +37,7 @@ def _run_module():
 
 
 RUN = _run_module()
+LIMITS = kinds.load("cnn").LIMITS
 
 
 def execute(mix_name: str, *, seconds: float = 1.5, trace: int = 0,
@@ -70,16 +71,16 @@ def test_tiny_cell_is_correct_and_reports_its_metrics(clean):
 def test_control_fails_the_limit(clean):
     # the same run put the reference in bfloat16 in the program's place
     assert clean["control"]["correct"] is False
-    assert clean["control"]["rel_gap_p90"] > RUN.REL_GAP_P90
-    assert clean["compared"]["rel_gap_p90"]["value"] < RUN.REL_GAP_P90
+    assert clean["control"]["rel_gap_p90"] > LIMITS["rel_gap_p90"]
+    assert clean["compared"]["rel_gap_p90"]["value"] < LIMITS["rel_gap_p90"]
 
 
 def test_planted_fault_in_a_few_answers_fails_the_max(clean):
     # one answer in 16 merged as if one of its arrived slots had timed out
     assert clean["fault"]["correct"] is False
-    assert clean["fault"]["max_rel_err"] > RUN.MAX_REL_ERR
-    assert clean["fault"]["rel_gap_p90"] <= RUN.REL_GAP_P90
-    assert clean["compared"]["max_rel_err"]["value"] < RUN.MAX_REL_ERR
+    assert clean["fault"]["max_rel_err"] > LIMITS["max_rel_err"]
+    assert clean["fault"]["rel_gap_p90"] <= LIMITS["rel_gap_p90"]
+    assert clean["compared"]["max_rel_err"]["value"] < LIMITS["max_rel_err"]
 
 
 def _break(monkeypatch, how: str) -> None:

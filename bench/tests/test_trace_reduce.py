@@ -42,6 +42,23 @@ def test_busy_kernel_time_and_idle_attribution():
     assert sum(gaps.values()) + t.busy_s() == pytest.approx(t.window_s)
 
 
+def test_program_spans_attribute_idle_gaps():
+    t = _made_up()
+    assert t.program == []
+    # program spans nest: each gap goes to the innermost one over its
+    # midpoint, else to the benchmark span idle_gaps chooses
+    t.program = [TR.Span("engine.batch", 0.2, 6.6, {"rows": 24}),
+                 TR.Span("server.stack", 0.45, 0.1),
+                 TR.Span("server.merge", 5.8, 0.2)]
+    got = dict(t.idle_by_phase())
+    # [0,1) mid 0.5 -> server.stack; [2.5,6) mid 4.25 -> engine.batch;
+    # [6.5,10) mid 8.25 -> no span: the engine's loop
+    assert got == pytest.approx({"server.stack": 1.0, "engine.batch": 3.5,
+                                 "engine_loop": 3.5})
+    assert sum(got.values()) + t.busy_s() == pytest.approx(t.window_s)
+    assert t.idle_by_phase([]) == t.idle_gaps(20)
+
+
 @pytest.mark.skipif(not RECORDED.exists(), reason="no recorded trace")
 def test_recorded_trace():
     t = TR.load(str(RECORDED))
@@ -63,3 +80,6 @@ def test_recorded_trace():
     assert sum(gaps.values()) == pytest.approx(t.window_s - t.busy_s(),
                                                rel=1e-6)
     assert "serve_batch" in gaps
+    # the recorded trace predates the program's spans: by phase is by gap
+    assert t.program == []
+    assert dict(t.idle_by_phase()) == pytest.approx(gaps, rel=1e-12)

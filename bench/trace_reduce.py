@@ -4,7 +4,11 @@ Device planes are those named ``/device:TPU:<n>``; on each, the ops line
 (``XLA Ops``) gives the intervals in which an operation ran, and the
 modules line (``XLA Modules``) the compiled programs. The host plane holds
 the benchmark's own ``TraceAnnotation`` spans (``dispatch``,
-``serve_batch``, ``poll_repair``, ``wait_due``). Every time is in seconds.
+``serve_batch``, ``poll_repair``, ``wait_due``) and the program's
+(``engine.*`` and ``server.*``, ``PROGRAM_SPANS``; each micro-batch is one
+``engine.batch`` span with the others nested in it), each program span
+with the stats it was given (``engine.batch``'s ``rows`` and ``pad_rows``,
+``server.merge``'s ``masked_slots``). Every time is in seconds.
 """
 from __future__ import annotations
 
@@ -13,11 +17,24 @@ import glob
 import os
 import re
 from collections import defaultdict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 HOST_SPANS = ("wait_due", "poll_repair", "serve_batch", "dispatch")
+PROGRAM_SPANS = (
+    "engine.batch", "engine.control", "engine.inputs", "engine.device_wait",
+    "engine.record", "server.draw", "server.stack", "server.slot_forward",
+    "server.slot_mask", "server.merge", "server.decode_ops",
+    "server.fused_step", "server.package")
+
+
+class Span(NamedTuple):
+    """One program span: its name, start and duration (s), and stats."""
+    name: str
+    t: float
+    d: float
+    stats: Optional[Dict] = None
 
 
 @dataclasses.dataclass
@@ -26,6 +43,8 @@ class Trace:
     ops: Dict[int, List[Tuple[str, float, float]]]      # chip -> (name, t, d)
     modules: Dict[int, List[Tuple[str, float, float]]]  # chip -> (name, t, d)
     host: List[Tuple[str, float, float]]          # benchmark spans (t, d)
+    # the program's spans by start time, the outer of two at one start first
+    program: List[Span] = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -72,9 +91,20 @@ class Trace:
         """Idle seconds on the chips, by what the host was doing: the
         innermost benchmark span over each gap's midpoint, else the
         engine's own loop."""
-        order = {name: i for i, name in enumerate(HOST_SPANS)}
-        spans = sorted(self.host, key=lambda s: s[1])
-        starts = np.asarray([s[1] for s in spans])
+        return self.idle_by_phase([], n)
+
+    def idle_by_phase(self, spans: Optional[Sequence] = None,
+                      n: int = 20) -> List[List]:
+        """Idle seconds on the chips by the innermost program span over each
+        gap's midpoint (the one that started last; program spans nest),
+        else the benchmark span as ``idle_gaps`` chooses it, else
+        ``engine_loop``. Sums to the window less the busy time, as
+        ``idle_gaps`` does. ``spans`` defaults to ``program``."""
+        spans = self.program if spans is None else spans
+        host = sorted(self.host, key=lambda s: s[1])
+        h_starts = np.asarray([s[1] for s in host])
+        p_starts = np.asarray([s[1] for s in spans])
+        p_ends = np.asarray([s[1] + s[2] for s in spans])
         tot: Dict[str, float] = defaultdict(float)
         for c in sorted(self.ops):
             b = self.busy(c)
@@ -83,15 +113,28 @@ class Trace:
                 if hi <= lo:
                     continue
                 mid = 0.5 * (lo + hi)
-                i = int(np.searchsorted(starts, mid, side="right"))
-                best = None
-                for name, t, d in spans[max(0, i - 64):i]:
-                    if t <= mid < t + d and (best is None
-                                             or order[name] < order[best]):
-                        best = name
-                tot[best or "engine_loop"] += (hi - lo) / len(self.ops)
+                i = int(np.searchsorted(p_starts, mid, side="right"))
+                j = max(0, i - 256)
+                over = np.flatnonzero(p_ends[j:i] > mid)
+                name = (spans[j + over[-1]][0] if over.size
+                        else _benchmark_span(host, h_starts, mid)
+                        or "engine_loop")
+                tot[name] += (hi - lo) / len(self.ops)
         return [[k, v] for k, v in sorted(tot.items(),
                                           key=lambda kv: -kv[1])[:n]]
+
+
+def _benchmark_span(host: Sequence, starts: np.ndarray,
+                    mid: float) -> Optional[str]:
+    """The benchmark span ``Trace.idle_gaps`` puts the instant ``mid``
+    under: the innermost of ``HOST_SPANS`` over it."""
+    order = {name: i for i, name in enumerate(HOST_SPANS)}
+    i = int(np.searchsorted(starts, mid, side="right"))
+    best = None
+    for name, t, d in host[max(0, i - 64):i]:
+        if t <= mid < t + d and (best is None or order[name] < order[best]):
+            best = name
+    return best
 
 
 def _base(name: str) -> str:
@@ -123,12 +166,14 @@ def find_xplane(log_dir: str) -> str:
 
 def load(path: str, window: Optional[Tuple[float, float]] = None) -> Trace:
     """Read ``path``. ``window`` (seconds, on the trace's clock) defaults to
-    the extent of the benchmark's host spans."""
+    the extent of the benchmark's host spans, else of the program's (a
+    trace of a live engine holds no benchmark spans)."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     ops: Dict[int, list] = {}
     modules: Dict[int, list] = {}
     host: List[Tuple[str, float, float]] = []
+    program: List[Span] = []
     for plane in pd.planes:
         m = re.fullmatch(r"/device:TPU:(\d+)", plane.name)
         if m:
@@ -147,9 +192,16 @@ def load(path: str, window: Optional[Tuple[float, float]] = None) -> Trace:
                     if e.name in HOST_SPANS:
                         host.append((e.name, e.start_ns * 1e-9,
                                      e.duration_ns * 1e-9))
+                    elif e.name in PROGRAM_SPANS:
+                        program.append(Span(e.name, e.start_ns * 1e-9,
+                                            e.duration_ns * 1e-9,
+                                            dict(e.stats)))
+    program.sort(key=lambda s: (s.t, -s.d))
     if window is None:
-        if not host:
-            raise ValueError("the trace holds none of the benchmark's spans")
-        window = (min(t for _, t, _ in host),
-                  max(t + d for _, t, d in host))
-    return Trace(window, ops, modules, host)
+        extent = host or program
+        if not extent:
+            raise ValueError("the trace holds none of the benchmark's or the "
+                             "program's spans")
+        window = (min(s[1] for s in extent),
+                  max(s[1] + s[2] for s in extent))
+    return Trace(window, ops, modules, host, program)

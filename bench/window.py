@@ -8,10 +8,12 @@ engine's event loop and the controller's repairs are all inside it. It
 overrides two private methods of the engine (``_dispatch``, ``_input``);
 a real-clock mode in the engine would let it go.
 
-``_input`` gives every request its own images from a pool made from the
-seed (the engine would reuse one array per row count). The window closes
-``seconds`` after the first dispatch; requests due in it are answered for
-``drain_s`` more, and whatever is still queued then is left unanswered.
+``_input`` gives every request its own input, which the configuration's
+kind (``bench/kinds/``) makes from the seed and the request's id (the
+engine would reuse one array per row count); filler rows and warm-up take
+the kind's ``warm`` arrays. The window closes ``seconds`` after the first
+dispatch; requests due in it are answered for ``drain_s`` more, and
+whatever is still queued then is left unanswered.
 """
 from __future__ import annotations
 
@@ -30,7 +32,6 @@ import numpy as np
 from bench import deploy
 from bench import traffic as T
 
-POOL_ROWS = 4096          # distinct images cycled through by the requests
 SAMPLE = 400              # requests kept for the comparison with the reference
 
 
@@ -38,10 +39,10 @@ class WindowClosed(Exception):
     """The window and its drain are over; the backlog stays queued."""
 
 
-def image_pool(cfg: Dict, seed: int, rows: int = POOL_ROWS) -> np.ndarray:
-    rng = np.random.default_rng([int(seed) % (1 << 64), 3])
-    return rng.standard_normal((rows,) + tuple(cfg["image_shape"]),
-                               np.float32)
+def sample(seed: int, due: int) -> set:
+    """Ids of the requests kept for the comparison with the reference."""
+    rng = np.random.default_rng([int(seed) % (1 << 64), 2])
+    return set(rng.choice(due, min(SAMPLE, due), replace=False).tolist())
 
 
 @dataclasses.dataclass
@@ -55,6 +56,7 @@ class BatchInfo:
     service_s: float
     computed: Optional[np.ndarray]     # (K,) slots the batch ran (any row)
     slot_widths: tuple                 # (K,) widths of the served slots
+    record: Any = None                 # the engine's own BatchRecord, whole
 
 
 class Recorder:
@@ -101,28 +103,27 @@ def make_engine_class():
         """``ServingEngine`` whose dispatches run on the real clock."""
 
         def __init__(self, server, config, *, recorder: Recorder,
-                     pool: np.ndarray, seconds: float, drain_s: float,
+                     inputs, seconds: float, drain_s: float,
                      on_dispatch: Callable[[float], None], **kw):
             super().__init__(server, config, **kw)
             self.recorder = recorder
-            self.pool = pool
+            self.inputs = inputs
             self.seconds = seconds
             self.drain_s = drain_s
             self.on_dispatch = on_dispatch
             self.t0: Optional[float] = None
             self.lateness: List[float] = []
             self.infos: List[BatchInfo] = []
-            self.offsets: Dict[int, int] = {}
             self.dispatched: List = []         # the engine's RequestRecords
-            self._pool_off = 0
-            self._calls: List[int] = []
+            self._pending: List = []           # this dispatch's requests
 
         def _input(self, rows):
-            off = self._pool_off if self._pool_off + rows <= len(self.pool) \
-                else 0
-            self._pool_off = off + rows
-            self._calls.append(off)
-            return self.pool[off:off + rows]
+            # the engine asks for each request's rows in order, then for
+            # the bucket's filler; its warm-up asks outside any dispatch
+            if self._pending:
+                r = self._pending.pop()
+                return self.inputs.request(r.rid, r.size)
+            return self.inputs.warm(rows)
 
         def _dispatch(self, now, reqs, bid):
             if self.t0 is None:
@@ -136,7 +137,7 @@ def make_engine_class():
                 raise WindowClosed
             self.lateness.append(real - now)
             self.on_dispatch(real)
-            self._calls = []
+            self._pending = reqs[::-1]
             self.recorder.current = reqs
             with jax.profiler.TraceAnnotation("dispatch"):
                 _, batch, events = super()._dispatch(max(now, real), reqs,
@@ -144,9 +145,8 @@ def make_engine_class():
             t_done = time.perf_counter() - self.t0
             self.recorder.current = None
             ok = self.recorder.last_ok
-            for r, off in zip(reqs, self._calls):
+            for r in reqs:
                 r.t_done = t_done if ok else np.inf
-                self.offsets[r.rid] = off
             self.dispatched += reqs
             batch.t_done = t_done
             rows = sum(r.size for r in reqs)
@@ -157,7 +157,8 @@ def make_engine_class():
                 computed = np.ones_like(computed)   # the megastep runs all
             self.infos.append(BatchInfo(
                 max(now, real), t_done, len(reqs), rows, padded,
-                batch.service_s, computed, tuple(self.server.part_dims or ())))
+                batch.service_s, computed, tuple(self.server.part_dims or ()),
+                batch))
             return t_done, batch, events
 
     return RealClockEngine
@@ -178,7 +179,7 @@ class Window:
     lateness: np.ndarray
     compiles: List[tuple]     # (real t, name, seconds, compile|cache) in window
     kept: Dict[int, tuple]               # rid -> (ServeResult, ir, zeroed)
-    offsets: Dict[int, int]              # rid -> pool row of its first image
+    inputs: Any                          # the kind's inputs of the requests
     failed: int
     trace_span: Optional[tuple] = None   # (t_start, t_stop) real seconds
 
@@ -198,7 +199,7 @@ def buckets(max_rows: int) -> List[int]:
     return out
 
 
-def precompile(server, pool: np.ndarray, max_rows: int) -> int:
+def precompile(server, inputs, max_rows: int) -> int:
     """Compile every slot's forward at every row bucket, several at a time
     (XLA compiles outside the interpreter lock); the engine's own warm-up
     then finds them compiled. Returns the number of programs."""
@@ -208,7 +209,7 @@ def precompile(server, pool: np.ndarray, max_rows: int) -> int:
 
     def one(job):
         k, b = job
-        return jax.block_until_ready(fns[k](pool[:b]))
+        return jax.block_until_ready(fns[k](inputs.warm(b)))
     # the programs hold this seed's weights as constants, so no later run
     # can use them: keep them out of the persistent cache (disk writes)
     floor = jax.config.jax_persistent_cache_min_compile_time_secs
@@ -223,7 +224,7 @@ def precompile(server, pool: np.ndarray, max_rows: int) -> int:
 
 
 def rehearse_drill(server, ir, down_sets: List[set], seed: int,
-                   pool: np.ndarray, max_rows: int) -> int:
+                   inputs, max_rows: int) -> int:
     """Replay the drill's repairs on a shallow copy of ``server`` (it shares
     the compiled slot forwards; ``migrate`` replaces fields and mutates
     nothing shared) and serve every row bucket after each, so that the
@@ -241,11 +242,11 @@ def rehearse_drill(server, ir, down_sets: List[set], seed: int,
             continue
         n += 1
         shadow.failure = FailureModel(forced_failures=sorted(down))
-        extra_warmup(shadow, pool, max_rows, rng=rng)
+        extra_warmup(shadow, inputs, max_rows, rng=rng)
     return n
 
 
-def extra_warmup(server, pool: np.ndarray, max_rows: int, *,
+def extra_warmup(server, inputs, max_rows: int, *,
                  rng: Optional[np.random.Generator] = None) -> None:
     """Compile the per-row masking of every row bucket under the server's
     own failure model (the engine's warm-up serves one request per bucket,
@@ -253,7 +254,7 @@ def extra_warmup(server, pool: np.ndarray, max_rows: int, *,
     rng = np.random.default_rng(0) if rng is None else rng
     for b in buckets(max_rows):
         for _ in range(3):
-            out = server.serve_batch([pool[i:i + 1] for i in range(b)],
+            out = server.serve_batch([inputs.warm(1, i) for i in range(b)],
                                      rng=rng)
             if out:
                 out[0].block_until_ready()
@@ -311,10 +312,8 @@ def serve_window(dep: deploy.Deployment, mix: Dict, seed: int,
 
     srv = dep.server
     times, sizes = T.arrivals(mix, seed, seconds)
-    rng = np.random.default_rng([int(seed) % (1 << 64), 2])
-    keep = set(rng.choice(len(times), min(SAMPLE, len(times)),
-                          replace=False).tolist())
-    pool = image_pool(dep.cfg, seed)
+    keep = sample(seed, len(times))
+    inputs = dep.kind.inputs(dep.cfg, seed, sizes)
     # the fleet's own per-request outage channel (each device's p_out)
     srv.failure = FailureModel()
 
@@ -360,15 +359,15 @@ def serve_window(dep: deploy.Deployment, mix: Dict, seed: int,
 
     max_rows = int(max(mix["sizes"])) * config.max_batch
     t = time.perf_counter()
-    n = precompile(srv, pool, max_rows)
+    n = precompile(srv, inputs, max_rows)
     t_pre = time.perf_counter() - t
     if drill:
         n_rep = rehearse_drill(srv, dep.ir, down_sets,
-                               int(drill.get("controller_seed", 0)), pool,
+                               int(drill.get("controller_seed", 0)), inputs,
                                max_rows)
         print(f"set-up: {n_rep} drill repairs rehearsed", flush=True)
     recorder = Recorder(srv, keep)
-    extra_warmup(srv, pool, max_rows)
+    extra_warmup(srv, inputs, max_rows)
     print(f"set-up: {n} slot programs compiled in {t_pre:.1f} s, then "
           f"warm-up {time.perf_counter() - t - t_pre:.1f} s; "
           f"{counter.summary()}", flush=True)
@@ -392,7 +391,7 @@ def serve_window(dep: deploy.Deployment, mix: Dict, seed: int,
             state["trace"][1] = real
 
     Engine = make_engine_class()
-    engine = Engine(srv, config, recorder=recorder, pool=pool,
+    engine = Engine(srv, config, recorder=recorder, inputs=inputs,
                     seconds=seconds, drain_s=float(mix.get("drain_s", 0.0)),
                     on_dispatch=on_dispatch, **kw)
     counter.events = []
@@ -428,5 +427,5 @@ def serve_window(dep: deploy.Deployment, mix: Dict, seed: int,
         t_dispatch=t_dispatch, t_done=t_done, quorum_ok=quorum_ok,
         batches=engine.infos, repairs=repairs,
         lateness=np.asarray(engine.lateness), compiles=compiles,
-        kept=recorder.kept, offsets=engine.offsets, failed=recorder.failed,
+        kept=recorder.kept, inputs=inputs, failed=recorder.failed,
         trace_span=tuple(state["trace"]) if state["trace"] else None)
